@@ -68,8 +68,11 @@ class Recorder:
 
     def __init__(self):
         self.rows = {}
+        self.failed = []   # names of rows a failed child process left
 
     def emit(self, bench: str, name: str, us: float, derived: str, **extra):
+        if extra.get("failed"):
+            self.failed.append(name)
         # small values (per-query latencies, ratios) keep their decimals
         print(f"{name},{us:.2f},{derived}" if us < 100 else
               f"{name},{us:.1f},{derived}")
@@ -448,6 +451,7 @@ def bench_sharded_routing():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"   # the child fakes host devices
     for shards in shard_counts:
         script = textwrap.dedent(f"""
             import json, os, time
@@ -599,6 +603,7 @@ def bench_persist():
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
+    env["JAX_PLATFORMS"] = "cpu"   # the child fakes host devices
     script = textwrap.dedent(f"""
         import json, os, tempfile, time
         os.environ["XLA_FLAGS"] = (
@@ -674,6 +679,7 @@ def bench_faults():
     REC.write("faults")
     if not result["ok"]:
         print("B9_crash_soak: DIVERGED (see rows)", file=sys.stderr)
+        REC.failed.append("B9_crash_soak")
 
 
 def bench_obs():
@@ -881,6 +887,9 @@ def main() -> None:
     for name, fn in BENCHES:
         if not picks or any(p in name for p in picks):
             fn()
+    if REC.failed:
+        print(f"FAILED rows: {', '.join(REC.failed)}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

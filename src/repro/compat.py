@@ -1,11 +1,11 @@
-"""Version-portability shims for JAX APIs that moved between releases.
+"""The repo's one convention for building meshes and shard_maps.
 
-The repo targets current JAX (``jax.shard_map``, ``jax.sharding.AxisType``,
-``check_vma``), but the container pins an older release where ``shard_map``
-still lives in ``jax.experimental`` (with ``check_rep`` instead of
-``check_vma``) and ``make_mesh`` has no ``axis_types`` parameter.  Every
-mesh/shard_map construction in the repo goes through these two wrappers so
-the version split lives in exactly one file.
+Every mesh and shard_map construction goes through these two wrappers so
+the choice lives in one file: meshes use ``Auto`` axis types (JAX's
+default is ``Explicit``, which would put shardings into array types), and
+shard_maps turn the varying-manual-axes check off, because the
+collectives inside are hand-written and the checker rejects valid manual
+patterns like the all_to_all routing.
 """
 
 from __future__ import annotations
@@ -13,48 +13,18 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
-
-try:  # jax >= 0.6
-    from jax.sharding import AxisType as _AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    _AxisType = None
-
-_HAS_JAX_SHARD_MAP = hasattr(jax, "shard_map")
+from jax.sharding import AxisType
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices: Optional[Sequence] = None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` with explicit ``Auto`` axis types where supported."""
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if _AxisType is not None:
-        kwargs["axis_types"] = (_AxisType.Auto,) * len(axis_names)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with ``Auto`` axis types."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """``jax.shard_map`` with replication checking off, on any JAX version.
-
-    All call sites in this repo disable the check (``check_vma=False`` /
-    ``check_rep=False``): the collectives inside are hand-written and the
-    checker rejects valid manual patterns like the all_to_all routing.
-    """
-    if _HAS_JAX_SHARD_MAP:
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-
-
-def cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` as a flat dict on any JAX version.
-
-    Older releases return a one-element list of per-computation dicts;
-    newer ones return the dict directly.
-    """
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0]
-    return ca
+    """``jax.shard_map`` with ``check_vma=False``."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

@@ -88,12 +88,17 @@ def owner_of(src: jax.Array, num_shards: int) -> jax.Array:
 
 def init_sharded(cfg: ShardedConfig, mesh: jax.sharding.Mesh) -> mc.MCState:
     """Global state: every array gains a leading ``num_shards`` dim, sharded
-    over ``cfg.axis``. Inside shard_map each shard sees its own MCState."""
-    one = mc.init(cfg.base)
-    stacked = jax.tree_util.tree_map(
-        lambda x: jnp.broadcast_to(x[None], (cfg.num_shards,) + x.shape), one)
+    over ``cfg.axis``. Inside shard_map each shard sees its own MCState.
+
+    Built by one program whose outputs are sharded, so each device
+    materialises only its own shard (no device ever holds the stack)."""
+    def build():
+        return jax.tree_util.tree_map(
+            lambda x: jnp.broadcast_to(x[None], (cfg.num_shards,) + x.shape),
+            mc.init(cfg.base))
+
     sharding = jax.sharding.NamedSharding(mesh, P(cfg.axis))
-    return jax.tree_util.tree_map(lambda x: jax.device_put(x, sharding), stacked)
+    return jax.jit(build, out_shardings=sharding)()
 
 
 def _state_spec(scfg: ShardedConfig):

@@ -84,6 +84,9 @@ class _Arming:
         self.fired = 0
 
 
+#: attribute naming the site on every exception an armed action raised
+INJECTED_ATTR = "mcq_failpoint"
+
 _mu = threading.Lock()
 _armed: Dict[str, _Arming] = {}
 _hits: Dict[str, int] = {}
@@ -131,7 +134,18 @@ def _slow_hit(name: str, ctx: dict) -> None:
             return
         arming.fired += 1
         action = arming.action
-    action(ctx)  # outside the lock: may raise, sleep, or never return
+    try:
+        action(ctx)  # outside the lock: may raise, sleep, or never return
+    except Exception as exc:
+        # tag it: the retry ladder takes injected faults whatever their type
+        setattr(exc, INJECTED_ATTR, name)
+        raise
+
+
+def injected_at(exc: BaseException) -> Optional[str]:
+    """The failpoint site whose armed action raised ``exc``; None for a
+    failure that was not injected."""
+    return getattr(exc, INJECTED_ATTR, None)
 
 
 # ---------------------------------------------------------------------------

@@ -24,7 +24,9 @@ per-row/per-item and association-free.
 first ``max_items``): the mode is a static kernel flag, not an unreachable
 sentinel threshold, so the contract never relies on a float that cannot be
 crossed.  The early-exit carry state lives in a scratch ref because values
-cannot thread through ``@pl.when`` bodies.
+cannot thread through ``@pl.when`` bodies.  Per-row values (totals, counts
+needed, the carry) are (Q, 1) columns and the threshold an SMEM scalar:
+Mosaic indexes no 1-D VMEM array at run time.
 """
 
 from __future__ import annotations
@@ -59,6 +61,22 @@ def auto_chunks(capacity: int, chunks: int) -> int:
     return 1
 
 
+def lane_cumsum(x: jax.Array) -> jax.Array:
+    """Inclusive int32 prefix sum along the lane (last) axis.
+
+    Mosaic has no cumsum lowering, so this is the log-step shifted-add
+    scan (Hillis-Steele): ``log2(C)`` lane rotations, selects and adds.
+    Integer addition wraps identically in any association order, so the
+    result is bit-identical to ``jnp.cumsum`` (contract A9)."""
+    n = x.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 1)
+    shift = 1
+    while shift < n:
+        x = x + jnp.where(lane >= shift, jnp.roll(x, shift, axis=-1), 0)
+        shift *= 2
+    return x
+
+
 def walk_chunks(load, totf, t, dst_out_ref, prob_out_ref, n_out_ref,
                 carry_ref, *, cap: int, max_items: int, chunks: int,
                 topk: bool):
@@ -66,40 +84,44 @@ def walk_chunks(load, totf, t, dst_out_ref, prob_out_ref, n_out_ref,
 
     ``load(k) -> (ck, dk)`` yields chunk ``k`` of the counts/dsts in
     priority order (reads happen inside the predicated body, so a skipped
-    chunk costs nothing).  ``carry_ref`` is an int32 (Q, 1) scratch holding
-    each row's exact cumulative count; outputs are initialised here and
-    written per chunk.  ``topk=True`` keeps every live item and disables
-    the early exit (there is no threshold to cross).
+    chunk costs nothing).  ``totf`` is the (Q, 1) float row total and
+    ``carry_ref`` an int32 (Q, 1) scratch holding each row's exact
+    cumulative count; ``n_out_ref`` is (Q, 1).  Outputs are initialised
+    here and written per chunk.  ``topk=True`` keeps every live item and
+    disables the early exit (there is no threshold to cross).
     """
     chunk = cap // chunks
     dst_out_ref[...] = jnp.full_like(dst_out_ref[...], EMPTY)
     prob_out_ref[...] = jnp.zeros_like(prob_out_ref[...])
     n_out_ref[...] = jnp.zeros_like(n_out_ref[...])
     carry_ref[...] = jnp.zeros_like(carry_ref[...])
-    tcnt = t * totf                                   # (Q,) float32
+    tcnt = t * totf                                   # (Q, 1) float32
 
     for k in range(chunks):
 
         def body(k=k):
             ck, dk = load(k)                          # (Q, chunk) int32
-            carry = carry_ref[:, 0]                   # exact int32 prefix
-            cum = carry[:, None] + jnp.cumsum(ck, axis=1)
+            cum = carry_ref[...] + lane_cumsum(ck)    # exact int32 prefix
             if topk:
                 needed = ck > 0
             else:
                 before = (cum - ck).astype(jnp.float32)
-                needed = (before < tcnt[:, None]) & (ck > 0)
+                needed = (before < tcnt) & (ck > 0)
             n_out_ref[...] = n_out_ref[...] + jnp.sum(
-                needed.astype(jnp.int32), axis=1)
+                needed.astype(jnp.int32), axis=1, keepdims=True)
             lo = k * chunk
             if lo < max_items:
                 hi = min(lo + chunk, max_items)
                 w = hi - lo
-                p = ck.astype(jnp.float32) / totf[:, None]
+                p = ck.astype(jnp.float32) / totf
                 keep = needed[:, :w]
-                dst_out_ref[:, lo:hi] = jnp.where(keep, dk[:, :w], EMPTY)
-                prob_out_ref[:, lo:hi] = jnp.where(keep, p[:, :w], 0.0)
-            carry_ref[:, 0] = cum[:, -1]
+                # a whole-row store needs no lane slice, which Mosaic
+                # refuses below 128 lanes on a row picked at run time
+                cols = (slice(None) if w == dst_out_ref.shape[1]
+                        else slice(lo, hi))
+                dst_out_ref[:, cols] = jnp.where(keep, dk[:, :w], EMPTY)
+                prob_out_ref[:, cols] = jnp.where(keep, p[:, :w], 0.0)
+            carry_ref[...] = cum[:, chunk - 1:]
 
         if topk or chunks == 1:
             body()
@@ -108,16 +130,17 @@ def walk_chunks(load, totf, t, dst_out_ref, prob_out_ref, n_out_ref,
             # threshold no later item can be needed (prefix counts are
             # monotone), so the whole chunk is predicated off.  Skipping
             # leaves carry stale, which keeps the block skipped — exact.
-            done = carry_ref[:, 0].astype(jnp.float32) >= tcnt
-            pl.when((k == 0) | ~jnp.all(done))(body)
+            open_rows = (carry_ref[...].astype(jnp.float32)
+                         < tcnt).astype(jnp.int32)
+            pl.when((k == 0) | (jnp.max(open_rows) > 0))(body)
 
 
-def _cdf_kernel(c_ref, d_ref, tot_ref, t_ref, dst_out_ref, prob_out_ref,
+def _cdf_kernel(t_ref, c_ref, d_ref, tot_ref, dst_out_ref, prob_out_ref,
                 n_out_ref, carry_ref, *, max_items: int, chunks: int,
                 topk: bool):
     cap = c_ref.shape[-1]
     chunk = cap // chunks
-    totf = jnp.maximum(tot_ref[...], 1).astype(jnp.float32)  # (Qb,)
+    totf = jnp.maximum(tot_ref[...], 1).astype(jnp.float32)  # (Qb, 1)
 
     def load(k):
         return (c_ref[:, k * chunk:(k + 1) * chunk],
@@ -136,7 +159,7 @@ def cdf_query_pallas(c_ord: jax.Array, d_ord: jax.Array, tot: jax.Array,
                      threshold=0.0, *, max_items: int = 16,
                      queries_per_block: int = DEFAULT_QUERIES_PER_BLOCK,
                      chunks: int = 1, topk: bool = False,
-                     interpret: bool = True):
+                     interpret: bool):
     """c_ord/d_ord: [B, C] counts/dsts in priority order (0 where missing),
     tot: [B]. Returns (dsts[B, max_items], probs[B, max_items], n_needed[B]).
     ``topk=True`` ignores the threshold and keeps every live item.
@@ -148,20 +171,21 @@ def cdf_query_pallas(c_ord: jax.Array, d_ord: jax.Array, tot: jax.Array,
     grid = (b // qb,)
     t_arr = jnp.asarray([threshold], jnp.float32)
     tile2d = pl.BlockSpec((qb, cap), lambda i: (i, 0))
-    tile1d = pl.BlockSpec((qb,), lambda i: (i,))
-    tscalar = pl.BlockSpec((1,), lambda i: (0,))
+    col = pl.BlockSpec((qb, 1), lambda i: (i, 0))
     tilek = pl.BlockSpec((qb, max_items), lambda i: (i, 0))
-    return pl.pallas_call(
+    dk, pk, nn = pl.pallas_call(
         functools.partial(_cdf_kernel, max_items=max_items, chunks=chunks,
                           topk=topk),
         grid=grid,
-        in_specs=[tile2d, tile2d, tile1d, tscalar],
-        out_specs=[tilek, tilek, tile1d],
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM), tile2d, tile2d,
+                  col],
+        out_specs=[tilek, tilek, col],
         out_shape=[
             jax.ShapeDtypeStruct((b, max_items), jnp.int32),
             jax.ShapeDtypeStruct((b, max_items), jnp.float32),
-            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1), jnp.int32),
         ],
         scratch_shapes=[pltpu.VMEM((qb, 1), jnp.int32)],
         interpret=interpret,
-    )(c_ord, d_ord, tot, t_arr)
+    )(t_arr, c_ord, d_ord, tot.reshape(b, 1))
+    return dk, pk, nn[:, 0]

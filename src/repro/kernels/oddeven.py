@@ -10,9 +10,8 @@ handful of lane shifts + selects, ideal for the TPU vector unit:
     c'[i] = c[i+1] if take_next else (c[i-1] if gave_prev else c[i])
 
 VMEM tiling: a (ROWS_PER_BLOCK, C) tile of both the count-in-order array and
-the permutation; grid over row blocks.  C (slab capacity) is the lane dim —
-configs keep it a multiple of 128 for MXU/VPU alignment; smaller capacities
-are padded by the ops.py wrapper.
+the permutation; grid over row blocks.  C (slab capacity) is the lane dim;
+a C below 128 (the serving configs use 64) fills part of each vreg.
 """
 
 from __future__ import annotations
@@ -33,8 +32,11 @@ def _compare_exchange(c, o, idx, parity):
     on = jnp.roll(o, -1, axis=1)
     op = jnp.roll(o, 1, axis=1)
     is_left = ((idx % 2) == parity) & (idx < cap - 1)
-    take_next = is_left & (c < cn)            # descending order target
-    gave_prev = jnp.roll(take_next, 1, axis=1)  # wrap safe: last lane masked
+    # descending order target; the mask travels as int32 because Mosaic
+    # cannot rotate a bool vector (wrap safe: the last lane is masked)
+    take_next = (is_left & (c < cn)).astype(jnp.int32)
+    gave_prev = jnp.roll(take_next, 1, axis=1) > 0
+    take_next = take_next > 0
     new_c = jnp.where(take_next, cn, jnp.where(gave_prev, cp, c))
     new_o = jnp.where(take_next, on, jnp.where(gave_prev, op, o))
     return new_c, new_o
@@ -56,7 +58,7 @@ def _oddeven_kernel(c_ref, o_ref, c_out_ref, o_out_ref, *, passes: int):
     jax.jit, static_argnames=("passes", "rows_per_block", "interpret"))
 def oddeven_pallas(c_ord: jax.Array, order: jax.Array, *, passes: int = 1,
                    rows_per_block: int = DEFAULT_ROWS_PER_BLOCK,
-                   interpret: bool = True):
+                   interpret: bool):
     """k odd-even passes. c_ord/order: [N, C], N divisible by rows_per_block
     (ops.py pads). Returns (c_ord', order')."""
     n, cap = c_ord.shape

@@ -1,9 +1,11 @@
 """jit'd public wrappers around the Pallas kernels.
 
 Handle padding to block multiples, the order-gather layout transform, and
-backend dispatch: ``impl='pallas'`` (interpret=True on CPU — the container
-has no TPU), ``impl='ref'`` (pure-jnp oracle), ``impl='auto'`` (pallas on
-TPU, ref otherwise — the ref *is* the XLA fast path on CPU).
+backend dispatch: ``impl='pallas'`` (compiled by Mosaic on a TPU, run by
+the Pallas interpreter on any other backend — how the CPU tests check
+kernel parity), ``impl='ref'`` (pure-jnp oracle), ``impl='auto'`` (pallas
+on TPU, ref otherwise — the ref *is* the XLA fast path on CPU).  On a TPU
+nothing falls back: a kernel that fails to compile raises to the caller.
 """
 
 from __future__ import annotations
@@ -150,12 +152,9 @@ def dh_find(rows: jax.Array, dsts: jax.Array,
         slots, found = _ref.dh_find_ref(rows, dsts, dh_keys, dh_vals,
                                         max_probes)
         return slots, found
-    rb = min(_pr.DEFAULT_ROWS_PER_BLOCK, dh_keys.shape[0])
-    keys_p, _ = _pad_rows(dh_keys, rb, -1)
-    vals_p, _ = _pad_rows(dh_vals, rb, -1)
     slots, found = _pr.probe_find_pallas(
-        rows, dsts, keys_p, vals_p, max_probes=max_probes,
-        rows_per_block=rb, interpret=not _on_tpu())
+        rows, dsts, dh_keys, dh_vals, max_probes=max_probes,
+        interpret=not _on_tpu())
     return slots, found.astype(bool)
 
 
@@ -177,7 +176,7 @@ def ht_find(keys_q: jax.Array, tab_keys: jax.Array, tab_vals: jax.Array,
         return slots, found
     slots, found = _pr.probe_find_pallas(
         rows, keys_q, tab_keys[None], tab_vals[None],
-        max_probes=max_probes, rows_per_block=1, interpret=not _on_tpu())
+        max_probes=max_probes, interpret=not _on_tpu())
     return slots, found.astype(bool)
 
 
@@ -232,6 +231,10 @@ def cdf_query_fused(rows: jax.Array, found: jax.Array,
         return _ref.cdf_query_fused_ref(rows, found, cnt, dst, order, tot,
                                         None if topk else threshold,
                                         max_items)
+    tile = min(_cg.SUBLANES, cnt.shape[0])
+    cnt, _ = _pad_rows(cnt, tile, 0)
+    dst, _ = _pad_rows(dst, tile, -1)
+    order, _ = _pad_rows(order, tile, 0)
     return _cg.cdf_query_fused_pallas(
         rows, found, cnt, dst, order, tot, 0.0 if topk else threshold,
         max_items=max_items, chunks=chunks, topk=topk,

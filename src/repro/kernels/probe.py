@@ -11,24 +11,28 @@ open-addressing tables probed independently:
     of every query): N = 1, H = the table size; all items probe table 0
     (``ops.ht_find`` — the kernelized ``hashtable.lookup_batch``).
 
-Each grid instance owns a (ROWS_PER_BLOCK, H) tile of the tables in VMEM and
-resolves the query list against it; items landing outside the tile are
-predicated off, exactly like ``slab_update``.
+The stack is read as one row-major slot array cut into tiles: eight whole
+per-row tables, or 1024 slots (8 x 128) of a large flat table.  Grid step
+i resolves query i: its table base ``rows[i] * H`` and home slot ride in
+SMEM (scalar prefetch), and the BlockSpec index maps DMA the tile holding
+the home slot plus the tile after it (wrapping), so the probe window
+always lies in the two tiles and a query moves two tiles of keys and of
+values whatever the table size.  A per-row table lies inside one tile; the
+flat table's window (``max_probes <= 1024``) wraps from its last tile into
+its first.
 
-The linear-probe loop is vectorised across the H lanes instead of iterated:
-for a query key ``d`` with home slot ``h0``, lane ``j`` sits at probe
-position ``p = (j - h0) mod H``.  The probe semantics of
+The linear-probe loop is vectorised across the window instead of iterated:
+for a query key ``d`` with home slot ``h0``, slot ``s`` of the table sits
+at probe position ``p = (s - h0) mod H``.  The probe semantics of
 ``hashtable.lookup`` — scan from ``h0``, stop at the key or the first EMPTY,
-give up after ``max_probes`` — become three lane-parallel reductions:
+give up after ``max_probes`` — become three reductions over the two tiles:
 
-  key_p   = min p over lanes holding the key      (H if none in window)
-  empty_p = min p over lanes holding EMPTY        (H if none in window)
-  found   = key_p < empty_p                       (TOMB lanes just probe on)
+  key_p   = min p over slots holding the key      (H if none in window)
+  empty_p = min p over slots holding EMPTY        (H if none in window)
+  found   = key_p < empty_p                       (TOMB slots just probe on)
 
-One table load + a handful of VPU ops per item; no scalar probe chains.  H
-is the lane dim (power of two by construction, multiple of 128 for real-TPU
-alignment at the sizes the configs use; smaller tables run in interpret mode
-off-TPU).
+Min/max reductions make a slot seen twice harmless (a table smaller than
+two tiles is loaded twice).  Results are written to SMEM outputs.
 """
 
 from __future__ import annotations
@@ -38,80 +42,103 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.hashtable import EMPTY, hash_u32
 
-DEFAULT_ROWS_PER_BLOCK = 256
+LANES = 128
+TILE_ROWS = 8
 
 
-def _probe_kernel(rows_ref, keys_q_ref, tab_keys_ref, tab_vals_ref,
-                  slot_out_ref, found_out_ref,
-                  *, rows_per_block: int, max_probes: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        slot_out_ref[...] = jnp.full_like(slot_out_ref[...], EMPTY)
-        found_out_ref[...] = jnp.zeros_like(found_out_ref[...])
-
-    r0 = pl.program_id(0) * rows_per_block
-    batch = rows_ref.shape[0]
-    h = tab_keys_ref.shape[1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, h), 1)
+def _probe_kernel(keys_q_ref, base_ref, home_ref, k0_ref, k1_ref, v0_ref,
+                  v1_ref, slot_out_ref, found_out_ref, *, h: int,
+                  tile_slots: int, n_tiles: int, max_probes: int):
+    i = pl.program_id(0)
+    d = keys_q_ref[i]
+    base = base_ref[i]
+    home = home_ref[i]
+    g0 = home // tile_slots
+    g1 = (g0 + 1) % n_tiles
+    shape = k0_ref.shape
+    pos = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * shape[1]
+           + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
     big = jnp.int32(h)
 
-    def body(i, _):
-        r = rows_ref[i] - r0
-        in_block = (r >= 0) & (r < rows_per_block)
-        rr = jnp.clip(r, 0, rows_per_block - 1)
-        row_keys = tab_keys_ref[pl.dslice(rr, 1), :]      # (1, H)
-        row_vals = tab_vals_ref[pl.dslice(rr, 1), :]
-        d = keys_q_ref[i]
-        h0 = (hash_u32(d) & jnp.uint32(h - 1)).astype(jnp.int32)
-        p = (lane - h0) & (h - 1)                     # probe position per lane
-        in_win = p < max_probes
-        is_key = in_win & (row_keys == d)
-        is_empty = in_win & (row_keys == EMPTY)
+    def window(g, keys, vals):
+        rel = g * tile_slots + pos - base       # slot within this table
+        p = (rel - (home - base)) & (h - 1)     # probe position
+        in_win = (rel >= 0) & (rel < h) & (p < max_probes)
+        is_key = in_win & (keys == d)
         key_p = jnp.min(jnp.where(is_key, p, big))
-        empty_p = jnp.min(jnp.where(is_empty, p, big))
-        found = in_block & (key_p < empty_p)
-        slot = jnp.sum(jnp.where(is_key & (p == key_p), row_vals, 0))
-        cur_s = slot_out_ref[pl.dslice(i, 1)]
-        cur_f = found_out_ref[pl.dslice(i, 1)]
-        out_s = jnp.where(in_block, jnp.where(found, slot, EMPTY), cur_s[0])
-        out_f = jnp.where(in_block, found.astype(jnp.int32), cur_f[0])
-        slot_out_ref[pl.dslice(i, 1)] = out_s.reshape(1).astype(jnp.int32)
-        found_out_ref[pl.dslice(i, 1)] = out_f.reshape(1).astype(jnp.int32)
-        return 0
+        empty_p = jnp.min(jnp.where(in_win & (keys == EMPTY), p, big))
+        return p, is_key, vals, key_p, empty_p
 
-    jax.lax.fori_loop(0, batch, body, 0)
+    p0, is_key0, vals0, key_p0, empty_p0 = window(g0, k0_ref[...],
+                                                  v0_ref[...])
+    p1, is_key1, vals1, key_p1, empty_p1 = window(g1, k1_ref[...],
+                                                  v1_ref[...])
+    key_p = jnp.minimum(key_p0, key_p1)
+    empty_p = jnp.minimum(empty_p0, empty_p1)
+    found = (base >= 0) & (key_p < empty_p)
+    slot = jnp.maximum(
+        jnp.max(jnp.where(is_key0 & (p0 == key_p), vals0, EMPTY)),
+        jnp.max(jnp.where(is_key1 & (p1 == key_p), vals1, EMPTY)))
+    slot_out_ref[i] = jnp.where(found, slot, EMPTY)
+    found_out_ref[i] = found.astype(jnp.int32)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("max_probes", "rows_per_block", "interpret"))
+@functools.partial(jax.jit, static_argnames=("max_probes", "interpret"))
 def probe_find_pallas(rows: jax.Array, keys_q: jax.Array,
                       tab_keys: jax.Array, tab_vals: jax.Array,
-                      *, max_probes: int = 64,
-                      rows_per_block: int = DEFAULT_ROWS_PER_BLOCK,
-                      interpret: bool = True):
+                      *, max_probes: int = 64, interpret: bool):
     """Batched open-addressing probe. rows[B] select a table out of
     ``tab_keys/tab_vals[N, H]`` (rows < 0 = padding); keys_q[B] are the
     probed keys.  Returns ``(slots[B], found[B] int32)`` with slot EMPTY
     where not found."""
     n, h = tab_keys.shape
-    rb = min(rows_per_block, n)
-    assert n % rb == 0, (n, rb)
-    grid = (n // rb,)
-    full = pl.BlockSpec(rows.shape, lambda i: (0,))
-    tile = pl.BlockSpec((rb, h), lambda i: (i, 0))
+    if n == 1 and h > TILE_ROWS * LANES:   # flat table: 1024-slot tiles
+        if max_probes > TILE_ROWS * LANES:
+            raise ValueError(f"max_probes={max_probes} exceeds the "
+                             f"{TILE_ROWS * LANES}-slot probe window")
+        tile_rows, lanes = TILE_ROWS, LANES
+        tab_keys = tab_keys.reshape(-1, LANES)
+        tab_vals = tab_vals.reshape(-1, LANES)
+    else:                                  # whole tables, 8 to a tile
+        tile_rows, lanes = min(TILE_ROWS, n), h
+        pad = (-n) % tile_rows
+        tab_keys = jnp.pad(tab_keys, ((0, pad), (0, 0)),
+                           constant_values=EMPTY)
+        tab_vals = jnp.pad(tab_vals, ((0, pad), (0, 0)),
+                           constant_values=EMPTY)
+    tile_slots = tile_rows * lanes
+    n_tiles = tab_keys.shape[0] // tile_rows
+
+    base = jnp.where(rows >= 0, rows * h, -1).astype(jnp.int32)
+    h0 = (hash_u32(keys_q) & jnp.uint32(h - 1)).astype(jnp.int32)
+    home = jnp.maximum(base, 0) + h0
+
+    def tile(shift):
+        return pl.BlockSpec(
+            (tile_rows, lanes),
+            lambda i, keys, base, home: (
+                (home[i] // tile_slots + shift) % n_tiles, 0))
+
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    b = rows.shape[0]
     slots, found = pl.pallas_call(
-        functools.partial(_probe_kernel, rows_per_block=rb,
-                          max_probes=max_probes),
-        grid=grid,
-        in_specs=[full, full, tile, tile],
-        out_specs=[full, full],
+        functools.partial(_probe_kernel, h=h, tile_slots=tile_slots,
+                          n_tiles=n_tiles, max_probes=max_probes),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b,),
+            in_specs=[tile(0), tile(1), tile(0), tile(1)],
+            out_specs=[smem, smem],
+        ),
         out_shape=[
-            jax.ShapeDtypeStruct(rows.shape, jnp.int32),
-            jax.ShapeDtypeStruct(rows.shape, jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32),
         ],
         interpret=interpret,
-    )(rows, keys_q, tab_keys, tab_vals)
+    )(keys_q.astype(jnp.int32), base, home, tab_keys, tab_keys, tab_vals,
+      tab_vals)
     return slots, found

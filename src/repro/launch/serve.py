@@ -37,6 +37,7 @@ from repro.data.synthetic import MarkovGraphSampler
 from repro.models.model import Model
 from repro.obs import metrics as obs_metrics
 from repro.obs.export import MetricsDumper, MetricsServer
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serve.engine import (Engine, ServeConfig, ShardedEngine,
                                 ShardedServeConfig)
 
@@ -196,6 +197,7 @@ def run_sharded(num_shards: int, bucket_factor: float, requests: int,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-7b")
     ap.add_argument("--smoke", action="store_true")

@@ -22,6 +22,8 @@ from typing import Callable, Dict, FrozenSet, List, Optional, Tuple
 
 import numpy as np
 
+from repro.faults.registry import injected_at
+
 
 @dataclasses.dataclass
 class WatchdogConfig:
@@ -108,7 +110,16 @@ class FailurePolicy:
 # ---------------------------------------------------------------------------
 
 
-class RetryBudgetExceeded(RuntimeError):
+class FaultEscalated(RuntimeError):
+    """A fault of the model (:func:`in_fault_model`) that the retry ladder
+    gave up on: persistent, or still failing after every attempt.  Chains
+    that fault as its ``__cause__``.  :func:`call_with_retry` reports a
+    fault of the model through this type only, so a caller that escalates
+    or degrades catches this type alone, and every other failure (a program
+    that fails to compile, a bug) passes through it unretried."""
+
+
+class RetryBudgetExceeded(FaultEscalated):
     """A transient fault survived every retry attempt; escalate."""
 
 
@@ -163,15 +174,28 @@ PERSISTENT_ERRNOS = frozenset({
 })
 
 
+def in_fault_model(exc: BaseException) -> bool:
+    """True for the failures the retry/degradation ladder models: IO
+    errors (``OSError``), shard-attributable dispatch faults, faults raised
+    by an armed failpoint, and an escalation or poisoned write path over
+    any of these.  Anything else — a compile or lowering error, a
+    bug — is no fault the ladder can absorb: it propagates unretried and is
+    never degraded into empty answers."""
+    return (isinstance(exc, (OSError, ShardDispatchError,
+                             FaultEscalated, EngineWriteUnavailable))
+            or injected_at(exc) is not None)
+
+
 def classify_io_error(exc: BaseException) -> str:
     """``"persistent"`` (retry cannot help) or ``"transient"``.
 
-    OSErrors are classified by errno; anything non-OSError coming out of
-    an IO edge (a dead thread, a device dispatch failure) is treated as
+    OSErrors are classified by errno; the other faults of the model (a
+    shard dispatch fault, an injected fault of any type) are treated as
     transient — one retry round is cheap and device hiccups recover.
     :class:`UnretryableIOError` is persistent whatever its errno: the
     raiser is telling us the operation cannot be retried from where it
-    failed (see the class docstring).
+    failed (see the class docstring).  :func:`call_with_retry` consults
+    this only for failures :func:`in_fault_model` admits.
     """
     if isinstance(exc, UnretryableIOError):
         return "persistent"
@@ -217,9 +241,11 @@ def call_with_retry(fn: Callable[[], object], *,
     """Run ``fn`` under the retry ladder.
 
     Transient faults back off and retry up to ``policy.max_attempts``
-    total tries; a persistent fault re-raises immediately (escalation is
-    the caller's job); an exhausted budget raises
-    :class:`RetryBudgetExceeded` from the last fault.  ``on_retry`` is
+    total tries; a persistent fault raises :class:`FaultEscalated` from it
+    at once, and an exhausted budget raises :class:`RetryBudgetExceeded`
+    (a ``FaultEscalated``) from the last fault — escalation is the
+    caller's job.  A failure outside the fault model
+    (:func:`in_fault_model`) re-raises unchanged on its first attempt.  ``on_retry`` is
     called with ``(attempt_index, exc)`` before each backoff sleep —
     the engine counts these into ``stats``.  ``metrics`` (an
     ``obs.Registry``) records each backoff delay into the
@@ -233,8 +259,10 @@ def call_with_retry(fn: Callable[[], object], *,
         try:
             return fn()
         except retry_on as exc:
-            if classify(exc) == "persistent":
+            if not in_fault_model(exc):
                 raise
+            if classify(exc) == "persistent":
+                raise FaultEscalated(f"persistent fault: {exc!r}") from exc
             last = exc
             try:
                 delay = next(delays)

@@ -45,6 +45,7 @@ from repro.persist import reshard as rs
 from repro.persist import snapshot as snapshot_io
 from repro.persist.wal import WriteAheadLog
 from repro.runtime.fault_tolerance import (EngineWriteUnavailable,
+                                           FaultEscalated,
                                            RetryPolicy, ShardHealth,
                                            StepWatchdog, WatchdogConfig,
                                            call_with_retry,
@@ -618,17 +619,22 @@ class ShardedEngine:
         On escalation (persistent errno or exhausted budget) nothing is
         durable and nothing was applied — the engine state is still
         consistent, so poison the write path (checkpoint-now inside) and
-        surface :class:`EngineWriteUnavailable` to the caller."""
+        surface :class:`EngineWriteUnavailable` to the caller.  A failure
+        outside the fault model is not retried, but it too poisons (what
+        reached the log is unknown) and re-raises unchanged."""
         try:
             return call_with_retry(
                 lambda: self.wal.append(src, dst, w),
                 policy=self.cfg.retry,
                 on_retry=self._count_retry("wal_retries"),
                 metrics=self.metrics)
-        except Exception as exc:
+        except FaultEscalated as exc:
             self._poison_locked(f"WAL append failed: {exc!r}")
             raise EngineWriteUnavailable(
                 f"write path poisoned: WAL append failed: {exc!r}") from exc
+        except Exception as exc:
+            self._poison_locked(f"WAL append failed: {exc!r}")
+            raise
 
     @requires_lock("_write_lock")
     def _apply_with_retry_locked(self, src, dst, w) -> None:
@@ -636,10 +642,12 @@ class ShardedEngine:
 
         ``_apply_locked`` commits nothing host-side until its publish
         succeeds, so re-invoking it after a fault re-runs an identical
-        plan.  Exhausted WITH a WAL, the batch is durable but unapplied —
-        letting callers continue would fork the chain from its own log,
-        so poison; ``restore()`` replays the ghost record and heals.
-        Without a WAL the state is simply unchanged: re-raise."""
+        plan.  Failing WITH a WAL — escalated, or outside the fault model
+        and so not retried (a program that fails to compile) — the batch
+        is durable but unapplied: letting callers continue would fork the
+        chain from its own log, so poison; ``restore()`` replays the ghost
+        record and heals.  Without a WAL the state is simply unchanged:
+        re-raise."""
         try:
             call_with_retry(
                 lambda: self._apply_locked(src, dst, w),
@@ -647,13 +655,18 @@ class ShardedEngine:
                 on_retry=self._count_retry("apply_retries"),
                 metrics=self.metrics)
             self.health.record_success_all()
-        except Exception as exc:
+        except FaultEscalated as exc:
             self._record_dispatch_failure(exc)
             if self.wal is not None:
                 self._poison_locked(
                     f"apply failed after durable append: {exc!r}")
                 raise EngineWriteUnavailable(
                     f"write path poisoned: apply failed: {exc!r}") from exc
+            raise
+        except Exception as exc:
+            if self.wal is not None:
+                self._poison_locked(
+                    f"apply failed after durable append: {exc!r}")
             raise
 
     @requires_lock("_write_lock")
@@ -812,9 +825,11 @@ class ShardedEngine:
 
         Degraded reads (DESIGN.md §12): items owned by a down shard are
         masked out before dispatch and answered empty (counted in
-        ``degraded_answers``); a faulting dispatch retries under
-        ``cfg.retry`` and, exhausted, the whole call degrades to empty
-        answers instead of failing the read path.  With
+        ``degraded_answers``); a dispatch fault of the fault model
+        retries under ``cfg.retry`` and, escalated (``FaultEscalated``),
+        the whole call degrades to empty answers instead of failing the
+        read path.  Any other error, e.g. a program that fails to
+        compile, raises on the first attempt.  With
         ``query_retry_budget > 0``, items the router would drop for skew
         re-dispatch against the same snapshot (spread round-robin across
         sender slices, so each round shrinks the per-slice owner groups);
@@ -857,7 +872,7 @@ class ShardedEngine:
                         metrics=self.metrics)
                     n_dropped = int(jnp.sum(dropped))
                     self.health.record_success_all()
-                except Exception as exc:
+                except FaultEscalated as exc:
                     # the read path never raises for dispatch faults: the
                     # whole call degrades to empty answers from zero shards
                     # (counted) — still sorted-descending, trivially.  A
@@ -925,7 +940,7 @@ class ShardedEngine:
                     policy=self.cfg.retry,
                     on_retry=self._count_retry("dispatch_retries"),
                     metrics=self.metrics)
-            except Exception as exc:
+            except FaultEscalated as exc:
                 self._record_dispatch_failure(exc)
                 break   # keep what we have; the rest counts as lost
             retried += int(idx.size)
@@ -948,8 +963,9 @@ class ShardedEngine:
         ``stats['topn_dropped']`` (last call's value is kept — it is a
         property of the current state, not a running total).  Rows owned
         by down shards are filtered from the merge (degraded reads,
-        DESIGN.md §12); a dispatch fault retries and, exhausted, the call
-        degrades to an empty merge rather than raising."""
+        DESIGN.md §12); a dispatch fault of the fault model retries and,
+        exhausted, the call degrades to an empty merge rather than
+        raising; any other error raises on the first attempt."""
         n = int(self.cfg.topn if n is None else n)
         with self.metrics.span("engine.topn"):
             return self._topn_inner(n)
@@ -972,7 +988,7 @@ class ShardedEngine:
                     metrics=self.metrics)
                 n_dropped = int(dropped)
                 self.health.record_success_all()
-            except Exception as exc:
+            except FaultEscalated as exc:
                 # read path never raises for dispatch faults: empty merge
                 self._record_dispatch_failure(exc)
                 srcs = jnp.full((n,), -1, jnp.int32)

@@ -29,6 +29,7 @@ from repro.core import sharded as sh
 from repro.persist import snapshot as snapshot_io
 from repro.persist.wal import SegmentRotationError, WriteAheadLog
 from repro.runtime.fault_tolerance import (EngineWriteUnavailable,
+                                           FaultEscalated,
                                            RetryBudgetExceeded, RetryPolicy,
                                            ShardDispatchError, ShardHealth,
                                            call_with_retry,
@@ -209,16 +210,18 @@ def test_retry_ladder_classification_and_budget():
     assert call_with_retry(flaky, policy=FAST, sleep=lambda s: None) == "ok"
     assert len(calls) == 3
 
-    # persistent: no second attempt
+    # persistent: no second attempt, escalated with the fault as its cause
     calls.clear()
 
     def full():
         calls.append(1)
         raise OSError(errno.ENOSPC, "disk full")
 
-    with pytest.raises(OSError):
+    with pytest.raises(FaultEscalated) as ei:
         call_with_retry(full, policy=FAST, sleep=lambda s: None)
     assert len(calls) == 1
+    assert not isinstance(ei.value, RetryBudgetExceeded)
+    assert ei.value.__cause__.errno == errno.ENOSPC
 
     # exhausted: RetryBudgetExceeded chains the last fault
     calls.clear()
@@ -733,6 +736,129 @@ def test_topn_dispatch_fault_degrades_not_raises(tmp_path):
     eng.close()
 
 
+@pytest.fixture
+def fresh_traces():
+    """Drop traced programs before and after, so a program traced under a
+    patched dispatch is never reused by another test."""
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
+def _uncompilable_topn_merge(probs, dsts, srcs, *, n, impl):
+    """``ops.topn_merge`` routed through a Pallas call that is compiled,
+    not interpreted: the CPU backend cannot lower it."""
+    from jax.experimental import pallas as pl
+    from repro.kernels import ref
+
+    def copy(x_ref, o_ref):
+        o_ref[...] = x_ref[...]
+
+    m_src, m_dst, m_p = ref.topn_merge_ref(probs, dsts, srcs, n)
+    m_p = pl.pallas_call(copy, out_shape=jax.ShapeDtypeStruct(
+        m_p.shape, m_p.dtype), interpret=False)(m_p)
+    return m_src, m_dst, m_p
+
+
+@pytest.mark.parametrize("op", ["query", "topn"])
+def test_lowering_error_raises_unretried(tmp_path, monkeypatch,
+                                         fresh_traces, op):
+    """A read program that fails to lower is a bug, not a fault of the
+    model: it reaches the caller on the first attempt — no retry, no
+    degraded empty answer."""
+    from repro.kernels import ops
+
+    eng = _engine(str(tmp_path), wal=False, snap=False)
+    eng.observe(*_batch(0))
+    if op == "query":   # the compiled kernels, on a backend without them
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        call = lambda: eng.query(np.arange(8))
+    else:
+        monkeypatch.setattr(ops, "topn_merge", _uncompilable_topn_merge)
+        call = lambda: eng.topn(4)
+    with pytest.raises(ValueError, match="interpret mode"):
+        call()
+    assert eng.stats["dispatch_retries"] == 0
+    assert eng.stats["degraded_answers"] == 0
+    eng.close()
+
+
+def test_lowering_error_on_observe_with_wal_poisons_and_restore_replays(
+        tmp_path, monkeypatch, fresh_traces):
+    """An update program that fails to lower AFTER the batch reached the
+    WAL: the error reaches the caller unretried, but the record is a ghost
+    (durable, unapplied), so the write path poisons exactly as for an
+    escalated fault, and restore() replays the record."""
+    from repro.kernels import ops
+
+    src0, dst0 = _batch(0)
+    src1, dst1 = _batch(1)
+    eng = _engine(str(tmp_path))
+    eng.observe(src0, dst0)
+    eng.checkpoint()
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    jax.clear_caches()      # retrace the update program under the patch
+    with pytest.raises(ValueError, match="interpret mode"):
+        eng.observe(src1, dst1)
+    monkeypatch.undo()
+    jax.clear_caches()
+    assert eng.stats["apply_retries"] == 0
+    assert eng.stats["write_errors"] == 1
+    assert not eng.write_available
+    assert eng._seq == 0 and eng.wal.last_seq == 1  # the ghost record
+    with pytest.raises(EngineWriteUnavailable):
+        eng.observe(src1, dst1)                 # seq never moves past it
+
+    result = eng.restore()
+    assert result["replayed"] >= 1 and eng._seq == 1
+    assert eng.write_available
+    healed_q = _query_state(eng)
+    eng.close()
+
+    oracle = _engine(str(tmp_path) + "_oracle")
+    oracle.observe(src0, dst0)
+    oracle.observe(src1, dst1)
+    for a, b in zip(healed_q, _query_state(oracle)):
+        np.testing.assert_array_equal(a, b)
+    oracle.close()
+
+
+@pytest.mark.parametrize("op", ["query", "topn"])
+@pytest.mark.parametrize("fault", [OSError(errno.EIO, "io fault"),
+                                   RuntimeError("device lost")])
+def test_injected_fault_still_degrades_reads(tmp_path, op, fault):
+    """Narrowing the ladder keeps what it models: an injected fault, an
+    IO error or any other type, still retries and degrades the read."""
+    eng = _engine(str(tmp_path), wal=False, snap=False)
+    eng.observe(*_batch(0))
+    faults.arm(f"engine.{op}_dispatch", fault)
+    if op == "query":
+        d, _, n = eng.query(np.arange(8))
+        assert (np.asarray(d) == -1).all() and (np.asarray(n) == 0).all()
+        assert eng.stats["degraded_answers"] == 8
+    else:
+        srcs, _, _ = eng.topn(4)
+        assert (np.asarray(srcs) == -1).all()
+        assert eng.stats["degraded_answers"] == 4
+    faults.reset()
+    assert eng.stats["dispatch_retries"] == FAST.max_attempts - 1
+    eng.close()
+
+
+def test_uninjected_runtime_error_is_not_retried():
+    """Outside the fault model (not an OSError, not injected, not a shard
+    dispatch fault) the ladder re-raises on the first attempt."""
+    calls = []
+
+    def broken():
+        calls.append(1)
+        raise RuntimeError("bug")
+
+    with pytest.raises(RuntimeError, match="bug"):
+        call_with_retry(broken, policy=FAST, sleep=lambda s: None)
+    assert len(calls) == 1
+
+
 @pytest.mark.skipif(jax.device_count() < 2,
                     reason="needs >= 2 devices (CI multi-device matrix; "
                            "XLA_FLAGS=--xla_force_host_platform_device_count=8)")
@@ -1020,3 +1146,22 @@ def test_query_overflow_retry_answers_skewed_batch(tmp_path):
     assert answered1 == 32 - eng.stats["query_lost"]
     assert answered1 > answered0
     eng.close()
+
+
+def test_soak_parent_never_imports_jax():
+    """The crash soak spawns a worker per life and checks recovery in a
+    child too, so its parent never holds an accelerator a child needs."""
+    import subprocess
+    import sys
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys\n"
+            "from tools.chaos.soak import run_soak\n"
+            "r = run_soak(1, rows=64, batch=32, min_steps=2, max_steps=2)\n"
+            "assert r['ok'], r\n"
+            "assert 'jax' not in sys.modules\n")
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.pathsep.join(
+        [os.path.join(repo, "src"), repo]))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-2000:]

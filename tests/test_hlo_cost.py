@@ -5,7 +5,6 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.launch import hlo_cost
 
 
@@ -59,7 +58,7 @@ def test_scan_matches_unrolled_xla_cost():
     co_s = _compile(scanned, ws, xs)
     co_u = _compile(unrolled, ws, xs)
     ours = hlo_cost.analyze(co_s.as_text()).flops
-    xla_unrolled = compat.cost_analysis(co_u)["flops"]
+    xla_unrolled = co_u.cost_analysis()["flops"]
     assert ours == pytest.approx(xla_unrolled, rel=0.01)
 
 

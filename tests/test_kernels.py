@@ -237,13 +237,8 @@ def test_dh_find_kernel_matches_ref(n, h):
         else:
             dsts[i] = 900_000 + i                # guaranteed miss
     rows_j, dsts_j = jnp.asarray(rows), jnp.asarray(dsts)
-    rb = min(prk.DEFAULT_ROWS_PER_BLOCK, n)
-    pad = (-n) % rb
-    keys_p = jnp.pad(keys, ((0, pad), (0, 0)), constant_values=ht.EMPTY)
-    vals_p = jnp.pad(vals, ((0, pad), (0, 0)), constant_values=ht.EMPTY)
     got_s, got_f = prk.probe_find_pallas(
-        rows_j, dsts_j, keys_p, vals_p, max_probes=64, rows_per_block=rb,
-        interpret=True)
+        rows_j, dsts_j, keys, vals, max_probes=64, interpret=True)
     want_s, want_f = ref.dh_find_ref(rows_j, dsts_j, keys, vals, 64)
     np.testing.assert_array_equal(np.asarray(got_f).astype(bool),
                                   np.asarray(want_f))
@@ -277,8 +272,7 @@ def test_dh_find_tombstone_chains_probe_through():
     rows = jnp.zeros((3,), jnp.int32)
     dsts = jnp.asarray(chain, jnp.int32)
     got_s, got_f = prk.probe_find_pallas(rows, dsts, keys, vals,
-                                      max_probes=16, rows_per_block=1,
-                                      interpret=True)
+                                      max_probes=16, interpret=True)
     want_s, want_f = ref.dh_find_ref(rows, dsts, keys, vals, 16)
     np.testing.assert_array_equal(np.asarray(got_f).astype(bool),
                                   np.asarray(want_f))
@@ -318,10 +312,11 @@ def test_decay_sort_matches_core_decay(impl):
 # ---------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("t_size", [64, 256])
+@pytest.mark.parametrize("t_size", [64, 256, 4096])
 def test_probe_flat_table_matches_core_lookup(t_size):
     """ops.ht_find == hashtable.lookup_batch on a real src table with
-    tombstones, for both dispatches."""
+    tombstones, for both dispatches (4096 slots: the two-tile window of a
+    table larger than one tile, wrapping at its end)."""
     from repro.kernels import ops
 
     rng = np.random.default_rng(t_size)
@@ -430,13 +425,14 @@ def test_cdf_gather_kernel_matches_fused_and_unfused_ref(n, c, t, chunks):
 # ---------------------------------------------------------------------------
 
 
-def _walk_fixture(rng, n_tokens=64, order=2):
+def _walk_fixture(rng, n_tokens=64, order=2, num_rows=256):
     """A chain learned from a noisy successor stream, plus its raw arrays."""
     from repro.core import mcprioq as mc
     from repro.core import speculative as spec
 
     ncfg = spec.NGramConfig(
-        order=order, mc=mc.MCConfig(num_rows=256, capacity=8, sort_passes=2))
+        order=order, mc=mc.MCConfig(num_rows=num_rows, capacity=8,
+                                    sort_passes=2))
     st = spec.init(ncfg)
     succ = rng.integers(0, n_tokens, (n_tokens,)).astype(np.int32)
     toks = np.empty((4, 256), np.int32)
@@ -468,6 +464,25 @@ def test_draft_walk_kernel_matches_scan_oracle(k):
     # dead lanes emit token 0 / ok False from the first step
     assert not np.asarray(got_o)[-2:].any()
     assert not np.asarray(got_t)[-2:].any()
+
+
+def test_draft_walk_small_table_probes_all_of_it():
+    """A src table shorter than max_probes (32 slots, 64 probes) is one
+    lane row: the walk probes the whole of it, as the oracle does."""
+    rng = np.random.default_rng(5)
+    st, ncfg, toks = _walk_fixture(rng, num_rows=8)
+    chain = st.chain
+    assert chain.src_table.keys.shape[0] < 64
+    window = jnp.asarray(toks[:, 40:42])
+    args = (window, chain.src_table.keys, chain.src_table.vals,
+            chain.slabs.cnt, chain.slabs.dst, chain.slabs.order[:, 0])
+    got_t, got_o = wkk.draft_walk_pallas(
+        *args, k=3, max_probes=64, queries_per_block=window.shape[0],
+        interpret=True)
+    want_t, want_o = ref.draft_walk_ref(*args, k=3, max_probes=64)
+    np.testing.assert_array_equal(np.asarray(got_t), np.asarray(want_t))
+    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(want_o))
+    assert np.asarray(want_o).any()
 
 
 def test_draft_walk_ok_is_prefix_monotone():

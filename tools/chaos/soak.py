@@ -9,8 +9,10 @@ load, or a self-SIGKILL armed *inside* a persistence failpoint via
 mid-fsync, mid-snapshot-write and mid-manifest-commit, not just between
 steps.  After each death the harness:
 
-  1. recovers in-process (``restore()`` = newest complete snapshot + WAL
-     replay), timing it — the recovery-time series is the B9 benchmark;
+  1. recovers in a fresh checker process (``restore()`` = newest complete
+     snapshot + WAL replay), timing it — the recovery-time series is the
+     B9 benchmark.  The parent never imports JAX, so on an accelerator
+     each worker and checker in turn gets the device;
   2. rebuilds an *oracle* engine with no persistence at all by replaying
      every durable WAL record from an empty chain through the same
      ``observe()`` pipeline;
@@ -136,21 +138,26 @@ def worker_main(args) -> None:
 # ---------------------------------------------------------------------------
 
 
+def _child_env() -> dict:
+    """A child's environment: the repo importable, nothing armed."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(REPO, "src"), REPO, env.get("PYTHONPATH", "")])
+    for key in ("MCQ_METRICS", "MCQ_METRICS_INCIDENT_DIR", "MCQ_FAILPOINTS"):
+        env.pop(key, None)
+    return env
+
+
 def _spawn_worker(workdir: str, rows: int, batch: int, seed: int,
                   snapshot_every: int, kill_site: Optional[str],
                   kill_hit: int, telemetry: bool = False,
                   poison: bool = False) -> subprocess.Popen:
-    env = dict(os.environ)
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(REPO, "src"), REPO, env.get("PYTHONPATH", "")])
+    env = _child_env()
     if telemetry:
         # arm the obs gate in the worker (spans, histograms, incidents) and
         # point the flight recorder's incident dumps at the workdir
         env["MCQ_METRICS"] = "1"
         env["MCQ_METRICS_INCIDENT_DIR"] = os.path.join(workdir, "incidents")
-    else:
-        env.pop("MCQ_METRICS", None)
-        env.pop("MCQ_METRICS_INCIDENT_DIR", None)
     if kill_site is not None:
         # a poison life raises ENOSPC (persistent) instead of SIGKILLing:
         # the write path poisons, dumps a flight-recorder incident, and the
@@ -158,8 +165,6 @@ def _spawn_worker(workdir: str, rows: int, batch: int, seed: int,
         # one, exercising the incident pipeline under real load
         action = "raise:28" if poison else "kill"
         env["MCQ_FAILPOINTS"] = f"{kill_site}={action}@nth:{kill_hit}"
-    else:
-        env.pop("MCQ_FAILPOINTS", None)
     return subprocess.Popen(
         [sys.executable, "-m", "tools.chaos.soak", "--worker",
          "--dir", workdir, "--rows", str(rows), "--batch", str(batch),
@@ -260,6 +265,23 @@ def _verify_recovery(workdir: str, rows: int, batch: int, seed: int):
     return recovery_s, durable, info["replayed"], mismatches
 
 
+def _verify_in_child(workdir: str, rows: int, batch: int, seed: int):
+    """:func:`_verify_recovery` in a fresh process, so the soak parent
+    never imports JAX: a parent holding the accelerator would leave every
+    later worker without one.  Same return value."""
+    proc = subprocess.run(
+        [sys.executable, "-m", "tools.chaos.soak", "--verify",
+         "--dir", workdir, "--rows", str(rows), "--batch", str(batch),
+         "--seed", str(seed)],
+        cwd=REPO, env=_child_env(), capture_output=True, text=True)
+    if proc.returncode != 0:
+        raise RuntimeError(f"recovery check exited {proc.returncode}: "
+                           f"{proc.stderr[-2000:]}")
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    return (out["recovery_s"], out["durable"], out["replayed"],
+            out["mismatches"])
+
+
 def _check_incidents(directory: str):
     """Every incident dump a poisoned worker left behind must parse and
     carry the flight-recorder payload (spans + metric deltas); returns
@@ -316,7 +338,7 @@ def run_soak(kills: int, *, rows: int = 256, batch: int = 128, seed: int = 0,
                                  snapshot_every, site, kill_hit,
                                  telemetry=telemetry, poison=poison)
             life = _run_life(proc, kill_after)
-            t_rec, durable, replayed, bad = _verify_recovery(
+            t_rec, durable, replayed, bad = _verify_in_child(
                 workdir, rows, batch, seed)
             ok = not bad
             all_ok &= ok
@@ -409,12 +431,20 @@ def main(argv: Optional[List[str]] = None) -> int:
                          "incident dumps (DESIGN.md §13)")
     ap.add_argument("--worker", action="store_true",
                     help=argparse.SUPPRESS)
+    ap.add_argument("--verify", action="store_true",
+                    help=argparse.SUPPRESS)
     args = ap.parse_args(argv)
 
-    if args.worker:
+    if args.worker or args.verify:
         if not args.dir:
-            ap.error("--worker requires --dir")
-        worker_main(args)
+            ap.error("--worker/--verify require --dir")
+        if args.worker:
+            worker_main(args)
+        else:
+            t_rec, durable, replayed, bad = _verify_recovery(
+                args.dir, args.rows, args.batch, args.seed)
+            print(json.dumps({"recovery_s": t_rec, "durable": durable,
+                              "replayed": replayed, "mismatches": bad}))
         return 0
 
     result = run_soak(args.kills, rows=args.rows, batch=args.batch,
