@@ -745,9 +745,10 @@ def bench_obs():
 
         us_loop = _time(gate, n=5)
         ns_per_record = us_loop * 1e3 / (2 * loops)
-        # an observe() crosses the gate a handful of times (span, traffic
-        # check, gauge); express that against the disarmed hot-path cost
-        ops_per_observe = 4
+        # an observe() crosses the gate seven times (six spans: observe,
+        # route, apply, two launches, device wait; the traffic check);
+        # express that against the disarmed hot-path cost
+        ops_per_observe = 7
         us_dis_obs = _time_paired([observe_with(False)], n=n)[0]
         gate_pct = (ops_per_observe * ns_per_record / 1e3) / us_dis_obs * 100
         REC.emit("obs", "B10_disarmed_gate", us_loop,

@@ -417,15 +417,17 @@ def update_batch_impl(
         return lambda st: _slow_path(
             st, p_src[:n], p_dst[:n], p_w[:n], p_mask[:n], cfg)
 
-    if small < limit:
-        state = jax.lax.cond(
-            n_new == 0, lambda st: st,
-            lambda st: jax.lax.cond(
-                n_new <= small, run_prefix(small), run_prefix(limit), st),
-            state)
-    else:
-        state = jax.lax.cond(
-            n_new == 0, lambda st: st, run_prefix(limit), state)
+    with jax.named_scope("mcq.new_edge_scan"):
+        if small < limit:
+            state = jax.lax.cond(
+                n_new == 0, lambda st: st,
+                lambda st: jax.lax.cond(
+                    n_new <= small, run_prefix(small), run_prefix(limit),
+                    st),
+                state)
+        else:
+            state = jax.lax.cond(
+                n_new == 0, lambda st: st, run_prefix(limit), state)
 
     # (5) lock-free bubble sort, through the kernel layer
     if cfg.sort_passes:

@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.analysis.invariants import kernel_op
-from repro.obs import tracing as _obs_tracing
 from repro.kernels import cdf_gather as _cg
 from repro.kernels import cdf_query as _cdf
 from repro.kernels import oddeven as _oe
@@ -51,21 +50,16 @@ def _pad_rows(x: jax.Array, mult: int, fill) -> Tuple[jax.Array, int]:
 
 
 def _annotate(fn):
-    """Opt-in profiler annotation around a jitted dispatcher (DESIGN.md
-    §13).  This wrapper stays OUTSIDE the jit (the jitted body must remain
-    pure — no module-global reads inside the trace), so the module-bool
-    gate costs one branch per call when disarmed.  When
-    ``obs.tracing.KERNEL_ANNOTATE`` is on, the dispatch traces under
-    ``jax.named_scope("mcq.<op>")`` and the op name lands in the HLO
-    metadata every profiler timeline shows.  Enable BEFORE the first
-    dispatch: jit caches the traced program, so already-compiled
-    signatures keep whatever scopes they were traced with."""
+    """Trace a jitted dispatcher under ``jax.named_scope("mcq.<op>")``
+    (DESIGN.md §13), so the op name lands in the HLO metadata every
+    profiler timeline shows.  A scope is metadata only: the persistent
+    compile cache's key strips it, and the compiled program is the same."""
+    scope = f"mcq.{fn.__name__}"
+
     @functools.wraps(fn)
     def dispatch(*args, **kwargs):
-        if _obs_tracing.KERNEL_ANNOTATE:
-            with jax.named_scope(f"mcq.{fn.__name__}"):
-                return fn(*args, **kwargs)
-        return fn(*args, **kwargs)
+        with jax.named_scope(scope):
+            return fn(*args, **kwargs)
     return dispatch
 
 

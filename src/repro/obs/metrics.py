@@ -12,10 +12,12 @@ concurrent updates" contract as the chain itself).
 
 Armed/disarmed follows the ``faults/registry.py`` pattern: a module-level
 bool gate.  Counters and gauges are ALWAYS recorded (they implement the
-engines' pre-existing stats contract); histograms, spans, traffic vectors
-and incident dumps only record while armed (``arm()`` /
-``MCQ_METRICS=1``), so the disarmed overhead on the serving hot paths is
-one global-bool read (bounded by benchmark B10).
+engines' pre-existing stats contract); histograms, traffic vectors and
+incident dumps only record while armed (``arm()`` / ``MCQ_METRICS=1``).
+Spans record while armed or while a profiler session is recording, so a
+profiled run carries them without arming.  Disarmed and unprofiled, the
+overhead on the serving hot paths is one global-bool read and one
+profiler check (bounded by benchmark B10).
 
 Metric *names* are a closed catalog (``METRIC_CATALOG``): every name
 recorded anywhere in ``src/`` must be declared here with a kind and help
@@ -32,6 +34,8 @@ import threading
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.obs import tracing
 
 #: metric kinds a catalog entry may declare
 KINDS = ("counter", "gauge", "histogram", "vector")
@@ -92,9 +96,25 @@ METRIC_CATALOG: Dict[str, Tuple[str, str]] = {
     "store_version": ("gauge", "current published epoch version"),
     # -- latency histograms (seconds) -----------------------------------
     "engine.observe": ("histogram", "observe() wall time (write cycle)"),
-    "engine.apply": ("histogram", "device apply+publish inside observe"),
+    "engine.route": ("histogram",
+                     "host plan of one batch: retry drain, down-shard "
+                     "deferral, padding, overflow prediction"),
+    "engine.apply": ("histogram",
+                     "enqueue of the update and maintain programs (each "
+                     "launch an engine.dispatch), and the publish"),
+    "engine.dispatch": ("histogram",
+                        "launch of one routed program; blocks while the "
+                        "runtime holds as many launches in flight as it "
+                        "takes"),
+    "engine.device_wait": ("histogram",
+                           "observe() blocked on the device counters, until "
+                           "the update and maintain programs have run"),
     "engine.query": ("histogram", "threshold-query wall time"),
+    "engine.query_wait": ("histogram",
+                          "query() blocked on its program's drop count"),
     "engine.topn": ("histogram", "global top-n read wall time"),
+    "engine.topn_wait": ("histogram",
+                         "topn() blocked on its program's drop count"),
     "engine.learn": ("histogram", "unsharded learner step wall time"),
     "wal.append": ("histogram", "WAL append (frame+write+flush) time"),
     "wal.fsync": ("histogram", "per-append WAL fsync time"),
@@ -155,16 +175,12 @@ def armed():
 
 def arm_from_env(env: Optional[Dict[str, str]] = None) -> Optional[str]:
     """Arm telemetry from the environment (the subprocess analogue of
-    ``faults.arm_from_env``): ``MCQ_METRICS`` truthy arms the gate,
-    ``MCQ_TRACE_KERNELS`` truthy enables kernel trace annotations, and the
-    returned ``MCQ_METRICS_INCIDENT_DIR`` (or None) is where an arming
+    ``faults.arm_from_env``): ``MCQ_METRICS`` truthy arms the gate, and
+    the returned ``MCQ_METRICS_INCIDENT_DIR`` (or None) is where an arming
     engine should dump incident files."""
     env = os.environ if env is None else env
     if env.get("MCQ_METRICS", "") not in ("", "0", "false", "no"):
         arm()
-    if env.get("MCQ_TRACE_KERNELS", "") not in ("", "0", "false", "no"):
-        from repro.obs import tracing
-        tracing.enable_kernel_annotations()
     return env.get("MCQ_METRICS_INCIDENT_DIR") or None
 
 
@@ -193,7 +209,8 @@ def bucket_edges(buckets_per_octave: int) -> np.ndarray:
 
 
 class _NoopSpan:
-    """Shared do-nothing context manager returned while disarmed."""
+    """Shared do-nothing context manager returned while disarmed and no
+    profiler records."""
 
     __slots__ = ()
 
@@ -238,7 +255,7 @@ class Registry:
 
     def __init__(self, *, vectors: Optional[Dict[str, int]] = None,
                  buckets_per_octave: int = DEFAULT_BUCKETS_PER_OCTAVE,
-                 flight_spans: int = 64,
+                 flight_spans: int = 1 << 15,
                  incident_dir: Optional[str] = None,
                  max_incidents: int = 32):
         self._bpo = int(buckets_per_octave)
@@ -251,7 +268,8 @@ class Registry:
         self._providers: List[Callable[[], Dict[str, int]]] = []
         self._gauges: Dict[str, float] = {}   # GIL-atomic stores, no lock
         # flight recorder: bounded deque appends are thread-safe; the ring
-        # holds the last N completed spans for incident dumps
+        # holds the last N completed spans, enough for every span of a
+        # profiled minute of serving; incident dumps write the newest 64
         import collections
         self._spans = collections.deque(maxlen=int(flight_spans))
         self.incident_dir = incident_dir
@@ -301,13 +319,13 @@ class Registry:
         vec[:m] += np.asarray(counts[:m], np.int64)
 
     def span(self, name: str, **attrs):
-        """A nestable monotonic-clock span context manager; records its
-        duration into the histogram ``name`` and pushes a record into the
-        flight recorder on exit.  Disarmed: a shared no-op."""
-        if not _ARMED:
+        """A nestable ``perf_counter`` span context manager, annotated on
+        the profiler's timeline; pushes a record into the flight recorder
+        on exit and, armed, its duration into the histogram ``name``.
+        Disarmed with no profiler recording: a shared no-op."""
+        if not (_ARMED or tracing.profiling()):
             return NOOP_SPAN
-        from repro.obs.tracing import Span
-        return Span(self, name, attrs)
+        return tracing.Span(self, name, attrs)
 
     def incident(self, reason: str, **ctx) -> Optional[str]:
         """Dump a flight-recorder incident file (see tracing.py); returns
@@ -315,7 +333,6 @@ class Registry:
         ``max_incidents``)."""
         if not _ARMED:
             return None
-        from repro.obs import tracing
         return tracing.dump_incident(self, reason, ctx)
 
     # -- read side --------------------------------------------------------
@@ -330,6 +347,11 @@ class Registry:
     def spans(self) -> List[dict]:
         """The flight recorder's current contents, oldest first."""
         return list(self._spans)
+
+    def spans_full(self) -> bool:
+        """True once the ring holds as many records as it can: the next
+        span drops the oldest, and older ones may be gone already."""
+        return len(self._spans) == self._spans.maxlen
 
     def _next_incident(self) -> int:
         with self._mu:

@@ -1,42 +1,64 @@
 """Spans and the flight recorder (DESIGN.md §13).
 
-A :class:`Span` is a nestable monotonic-clock context manager: entering
-pushes its name onto a thread-local stack (so a child records its parent),
-exiting records the duration into the registry histogram of the same name
-and appends a compact record to the registry's fixed-size ring.  Exit is
-exception-safe — a raising body still closes the span (flagged
-``error=True``) and re-raises.
+A :class:`Span` is a nestable context manager timed on
+``time.perf_counter``.  Entering pushes it onto a thread-local stack, so a
+child records its parent's name and shares its call id: every span of one
+top-level call carries the same ``call``.  It also enters a
+``jax.profiler.TraceAnnotation`` of the same name, so a profiler session
+shows the span on its host timeline, on the clock of the device events.
+Exiting records the duration into the registry histogram of the same name
+(armed only) and appends ``{name, start_s, end_s, parent, call, thread,
+error[, attrs]}`` to the registry's ring.  Exit is exception-safe: a
+raising body still closes the span (``error=True``) and re-raises.
+
+This module does not load JAX: a process that never did (a WAL tool, the
+chaos soak's parent) has no profiler to annotate, and its armed spans
+record without the annotation.
 
 ``dump_incident`` is the flight recorder's readout: on a fault event
 (write-path poison, shard strike-out, degraded read) the engine calls
-``registry.incident(reason, **ctx)`` which snapshots the last N spans plus
+``registry.incident(reason, **ctx)`` which snapshots the newest spans plus
 the scalar deltas since the previous incident and writes one JSON file —
 tmp + ``os.replace`` so a crash mid-dump never leaves a torn incident —
 making a chaos-soak kill diagnosable post-mortem.
-
-``KERNEL_ANNOTATE`` gates the opt-in trace-annotation wrapper in
-``kernels/ops.py``: when enabled, each dispatcher traces under a
-``jax.named_scope("mcq.<op>")`` so profiler timelines carry op names.
-Enable it *before* the first dispatch — jit caches the traced computation,
-so scopes only land in programs compiled while the flag is on.
 """
 
 from __future__ import annotations
 
+import itertools
 import json
 import os
+import sys
 import tempfile
 import threading
 import time
 from typing import Optional
 
-#: module-bool gate for jax.named_scope annotations around kernel dispatch
-KERNEL_ANNOTATE = False
+#: spans an incident dump writes, newest last
+INCIDENT_SPANS = 64
+
+#: call ids of top-level spans (``next`` on a count is atomic under the GIL)
+_CALL_IDS = itertools.count()
 
 
-def enable_kernel_annotations(on: bool = True) -> None:
-    global KERNEL_ANNOTATE
-    KERNEL_ANNOTATE = on
+#: ``jax.profiler.TraceAnnotation`` once the process has loaded JAX
+_ANNOTATION = None
+
+
+def _annotation():
+    """The annotation class, or None while the process has not loaded JAX
+    (then no profiler can be recording)."""
+    global _ANNOTATION
+    if _ANNOTATION is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    return _ANNOTATION
+
+
+def profiling() -> bool:
+    """True while a profiler session records host events."""
+    annotation = _ANNOTATION or _annotation()
+    return annotation is not None and annotation.is_enabled()
 
 
 def _span_stack(registry) -> list:
@@ -48,31 +70,44 @@ def _span_stack(registry) -> list:
 
 
 class Span:
-    """One timed region; created armed-only via ``Registry.span``."""
+    """One timed region; made by ``Registry.span`` while armed or traced."""
 
-    __slots__ = ("_registry", "name", "attrs", "parent", "_t0")
+    __slots__ = ("_registry", "name", "attrs", "parent", "call", "_t0",
+                 "_trace")
 
     def __init__(self, registry, name: str, attrs: Optional[dict] = None):
         self._registry = registry
         self.name = name
         self.attrs = dict(attrs) if attrs else None
         self.parent = None
+        self.call = None
         self._t0 = 0.0
+        annotation = _ANNOTATION or _annotation()
+        self._trace = annotation(name) if annotation is not None else None
 
     def __enter__(self):
         stack = _span_stack(self._registry)
-        self.parent = stack[-1] if stack else None
-        stack.append(self.name)
-        self._t0 = time.monotonic()
+        if stack:
+            self.parent = stack[-1].name
+            self.call = stack[-1].call
+        else:
+            self.call = next(_CALL_IDS)
+        stack.append(self)
+        if self._trace is not None:
+            self._trace.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.monotonic() - self._t0
+        t1 = time.perf_counter()
+        if self._trace is not None:
+            self._trace.__exit__(exc_type, exc, tb)
         stack = _span_stack(self._registry)
-        if stack and stack[-1] == self.name:
+        if stack and stack[-1] is self:
             stack.pop()
-        self._registry.hist_record(self.name, dur)
-        rec = {"name": self.name, "dur_s": dur, "parent": self.parent,
+        self._registry.hist_record(self.name, t1 - self._t0)
+        rec = {"name": self.name, "start_s": self._t0, "end_s": t1,
+               "parent": self.parent, "call": self.call,
                "thread": threading.current_thread().name,
                "error": exc_type is not None}
         if self.attrs:
@@ -90,7 +125,7 @@ def dump_incident(registry, reason: str, ctx: dict) -> Optional[str]:
     directory = registry.incident_dir
     if directory is None or seq > registry.max_incidents:
         return None
-    spans = registry.spans()
+    spans = registry.spans()[-INCIDENT_SPANS:]
     scalars = registry.scalars()
     deltas = registry.incident_delta(scalars)
     payload = {

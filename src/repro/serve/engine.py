@@ -729,61 +729,64 @@ class ShardedEngine:
         exactly as they were — the caller's retry re-runs an identical
         plan, and a non-retried fault changes nothing.
         """
-        scfg = self.cfg.sharded
-        n_shards = scfg.num_shards
-        src = np.asarray(src, np.int32).reshape(-1)
-        dst = np.asarray(dst, np.int32).reshape(-1)
-        w = np.asarray(w, np.int32).reshape(-1)
-        tries = np.zeros(src.shape, np.int32)
-        budget = self.cfg.route_retry_budget
-        remaining = self._retry_queue
-        if budget > 0 and remaining:
-            drained, remaining = self._drain_plan(remaining)
-            src = np.concatenate([src] + [c[0] for c in drained])
-            dst = np.concatenate([dst] + [c[1] for c in drained])
-            w = np.concatenate([w] + [c[2] for c in drained])
-            tries = np.concatenate([tries] + [c[3] for c in drained])
-        defer_plan, lost_down = [], 0
-        down = self.health.down
-        if down:
-            owner = np.asarray(scfg.resolved_ownership().owner_of(
-                jnp.asarray(src, jnp.int32)))
-            hit = np.isin(owner, list(down)) & (src >= 0)
-            if hit.any():
-                for s_id in sorted(int(x) for x in set(owner[hit])):
-                    sel = hit & (owner == s_id)
-                    defer_plan.append((s_id, src[sel].copy(),
-                                       dst[sel].copy(), w[sel].copy()))
-                src = np.where(hit, -1, src).astype(np.int32)
-                dst = np.where(hit, 0, dst).astype(np.int32)
-                w = np.where(hit, 0, w).astype(np.int32)
-        pad = (-src.size) % n_shards
-        if pad:
-            src = np.concatenate([src, np.full(pad, -1, np.int32)])
-            dst = np.concatenate([dst, np.zeros(pad, np.int32)])
-            w = np.concatenate([w, np.zeros(pad, np.int32)])
-            tries = np.concatenate([tries, np.zeros(pad, np.int32)])
-        requeue, retried, lost_skew = None, 0, 0
-        if budget > 0:
-            drop = sh.predict_route_overflow(scfg, src)
-            if drop.any():
-                again = drop & (tries < budget)
-                dead = drop & ~again
-                retried = int(again.sum())
-                lost_skew = int(dead.sum())
-                if retried:
-                    requeue = (src[again].copy(), dst[again].copy(),
-                               w[again].copy(), tries[again] + 1)
-                src = np.where(drop, -1, src).astype(np.int32)
-                dst = np.where(drop, 0, dst).astype(np.int32)
-                w = np.where(drop, 0, w).astype(np.int32)
+        with self.metrics.span("engine.route"):
+            scfg = self.cfg.sharded
+            n_shards = scfg.num_shards
+            src = np.asarray(src, np.int32).reshape(-1)
+            dst = np.asarray(dst, np.int32).reshape(-1)
+            w = np.asarray(w, np.int32).reshape(-1)
+            tries = np.zeros(src.shape, np.int32)
+            budget = self.cfg.route_retry_budget
+            remaining = self._retry_queue
+            if budget > 0 and remaining:
+                drained, remaining = self._drain_plan(remaining)
+                src = np.concatenate([src] + [c[0] for c in drained])
+                dst = np.concatenate([dst] + [c[1] for c in drained])
+                w = np.concatenate([w] + [c[2] for c in drained])
+                tries = np.concatenate([tries] + [c[3] for c in drained])
+            defer_plan, lost_down = [], 0
+            down = self.health.down
+            if down:
+                owner = np.asarray(scfg.resolved_ownership().owner_of(
+                    jnp.asarray(src, jnp.int32)))
+                hit = np.isin(owner, list(down)) & (src >= 0)
+                if hit.any():
+                    for s_id in sorted(int(x) for x in set(owner[hit])):
+                        sel = hit & (owner == s_id)
+                        defer_plan.append((s_id, src[sel].copy(),
+                                           dst[sel].copy(), w[sel].copy()))
+                    src = np.where(hit, -1, src).astype(np.int32)
+                    dst = np.where(hit, 0, dst).astype(np.int32)
+                    w = np.where(hit, 0, w).astype(np.int32)
+            pad = (-src.size) % n_shards
+            if pad:
+                src = np.concatenate([src, np.full(pad, -1, np.int32)])
+                dst = np.concatenate([dst, np.zeros(pad, np.int32)])
+                w = np.concatenate([w, np.zeros(pad, np.int32)])
+                tries = np.concatenate([tries, np.zeros(pad, np.int32)])
+            requeue, retried, lost_skew = None, 0, 0
+            if budget > 0:
+                drop = sh.predict_route_overflow(scfg, src)
+                if drop.any():
+                    again = drop & (tries < budget)
+                    dead = drop & ~again
+                    retried = int(again.sum())
+                    lost_skew = int(dead.sum())
+                    if retried:
+                        requeue = (src[again].copy(), dst[again].copy(),
+                                   w[again].copy(), tries[again] + 1)
+                    src = np.where(drop, -1, src).astype(np.int32)
+                    dst = np.where(drop, 0, dst).astype(np.int32)
+                    w = np.where(drop, 0, w).astype(np.int32)
         failpoint("engine.apply", items=int(src.size))
         with self.metrics.span("engine.apply"):
             snap = self.store.acquire()
             try:
-                state = self._update(snap.state, jnp.asarray(src),
-                                     jnp.asarray(dst), jnp.asarray(w))
-                state = self._maintain(state)
+                args = (jnp.asarray(src), jnp.asarray(dst), jnp.asarray(w))
+                with self.metrics.span("engine.dispatch"):
+                    state = self._update(snap.state, *args)
+                with self.metrics.span("engine.dispatch"):
+                    state = self._maintain(state)
             finally:
                 self.store.release(snap)
             failpoint("engine.publish")
@@ -803,7 +806,8 @@ class ShardedEngine:
                 deferred += int(qsrc.size)
             else:
                 lost_down += int(qsrc.size)
-        counters = mc.counter_stats(state)
+        with self.metrics.span("engine.device_wait"):
+            counters = mc.counter_stats(state)
         with self._stats_lock:
             self.stats["updates"] += 1
             self.stats.update(counters)
@@ -870,7 +874,8 @@ class ShardedEngine:
                         policy=self.cfg.retry,
                         on_retry=self._count_retry("dispatch_retries"),
                         metrics=self.metrics)
-                    n_dropped = int(jnp.sum(dropped))
+                    with self.metrics.span("engine.query_wait"):
+                        n_dropped = int(jnp.sum(dropped))
                     self.health.record_success_all()
                 except FaultEscalated as exc:
                     # the read path never raises for dispatch faults: the
@@ -908,7 +913,8 @@ class ShardedEngine:
         """Single routed query dispatch; the failpoint sits inside so a
         retry round re-traverses it (nth-hit triggers model transients)."""
         failpoint("engine.query_dispatch", items=int(src.shape[0]))
-        return fn(snap.state, src)
+        with self.metrics.span("engine.dispatch"):
+            return fn(snap.state, src)
 
     def _query_overflow_retry(self, fn, snap, src, b, d, p, n):
         """In-call overflow retry: re-dispatch the items the router would
@@ -986,7 +992,8 @@ class ShardedEngine:
                     policy=self.cfg.retry,
                     on_retry=self._count_retry("dispatch_retries"),
                     metrics=self.metrics)
-                n_dropped = int(dropped)
+                with self.metrics.span("engine.topn_wait"):
+                    n_dropped = int(dropped)
                 self.health.record_success_all()
             except FaultEscalated as exc:
                 # read path never raises for dispatch faults: empty merge
@@ -1033,7 +1040,8 @@ class ShardedEngine:
         """Single cross-shard merge dispatch (failpoint inside: retries
         re-traverse it)."""
         failpoint("engine.topn_dispatch")
-        return fn(snap.state)
+        with self.metrics.span("engine.dispatch"):
+            return fn(snap.state)
 
     # ------------------------------------------------------------------
     # durability & elasticity (DESIGN.md §10)

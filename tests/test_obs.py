@@ -360,3 +360,144 @@ def test_poison_fires_incident_dump(tmp_path):
     assert doc["reason"] == "poison"
     assert any(sp["name"] == "engine.observe" for sp in doc["spans"])
     assert doc["deltas"], "incident carries no metric deltas"
+
+
+# ---------------------------------------------------------------------------
+# spans under the profiler (registry disarmed)
+# ---------------------------------------------------------------------------
+
+WRITE_CHILDREN = ("engine.route", "engine.apply", "engine.device_wait")
+# every span of one call, by name, with its parent's name
+CALL_SPANS = {
+    "engine.observe": {"engine.route": "engine.observe",
+                       "engine.apply": "engine.observe",
+                       "engine.dispatch": "engine.apply",
+                       "engine.device_wait": "engine.observe"},
+    "engine.query": {"engine.dispatch": "engine.query",
+                     "engine.query_wait": "engine.query"},
+    "engine.topn": {"engine.dispatch": "engine.topn",
+                    "engine.topn_wait": "engine.topn"},
+}
+
+
+@pytest.fixture(scope="module")
+def traced_engine(tmp_path_factory):
+    """One observe, query and top-n of a small engine inside a profiler
+    session with the registry disarmed; returns the engine, the ring's
+    records and the trace directory."""
+    import jax
+    obs.disarm()
+    eng = _engine(str(tmp_path_factory.mktemp("eng")), wal=False)
+    eng.observe(*_batch())            # compile outside the trace
+    eng.query(np.arange(8).astype(np.int32))
+    eng.topn()
+    assert eng.metrics.spans() == []
+    trace_dir = str(tmp_path_factory.mktemp("trace"))
+    jax.profiler.start_trace(trace_dir)
+    try:
+        eng.observe(*_batch(1))
+        eng.query(np.arange(8).astype(np.int32))
+        eng.topn()
+    finally:
+        jax.profiler.stop_trace()
+    return eng, eng.metrics.spans(), trace_dir
+
+
+def _calls(records):
+    by = {}
+    for r in records:
+        by.setdefault(r["call"], []).append(r)
+    return by
+
+
+@pytest.mark.parametrize("top", sorted(CALL_SPANS))
+def test_profiled_spans_nest_in_one_call(traced_engine, top):
+    _, records, _ = traced_engine
+    calls = [c for c in _calls(records).values()
+             if any(r["name"] == top for r in c)]
+    assert len(calls) == 1
+    call = {r["name"]: r for r in calls[0]}
+    assert set(call) == {top, *CALL_SPANS[top]}
+    assert call[top]["parent"] is None and call[top]["error"] is False
+    for child in calls[0]:
+        if child is call[top]:
+            continue
+        parent = call[CALL_SPANS[top][child["name"]]]
+        assert child["parent"] == parent["name"]
+        assert child["call"] == parent["call"]
+        assert child["start_s"] <= child["end_s"]
+        assert parent["start_s"] <= child["start_s"]
+        assert child["end_s"] <= parent["end_s"]
+    # the write cycle launches two programs: update, then maintain
+    launches = [r for r in calls[0] if r["name"] == "engine.dispatch"]
+    assert len(launches) == (2 if top == "engine.observe" else 1)
+    if top == "engine.observe":
+        seq = [call[n] for n in WRITE_CHILDREN]
+        assert all(a["end_s"] <= b["start_s"] for a, b in zip(seq, seq[1:]))
+
+
+def test_profiled_spans_are_host_events_of_the_trace(traced_engine):
+    import glob
+    from jax.profiler import ProfileData
+    _, _, trace_dir = traced_engine
+    files = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True)
+    assert files
+    names = set()
+    for plane in ProfileData.from_file(files[0]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    want = {name for top, spans in CALL_SPANS.items()
+            for name in (top, *spans)}
+    assert want <= names
+
+
+def test_profiled_spans_leave_histograms_and_stop_with_the_trace(
+        traced_engine):
+    eng, records, _ = traced_engine
+    hists = eng.metrics.snapshot()["histograms"]
+    assert hists["engine.observe"]["count"] == 0   # histograms: armed only
+    eng.observe(*_batch(2))
+    eng.query(np.arange(8).astype(np.int32))
+    assert eng.metrics.spans() == records
+    assert eng.metrics.span("engine.query") is obs.NOOP_SPAN
+
+
+def test_incident_dump_writes_only_the_newest_spans(tmp_path):
+    from repro.obs import tracing
+    reg = obs.Registry(incident_dir=str(tmp_path))
+    with obs.armed():
+        for i in range(tracing.INCIDENT_SPANS + 10):
+            with reg.span("engine.query", i=i):
+                pass
+        with open(reg.incident("poison")) as f:
+            spans = json.load(f)["spans"]
+    assert len(reg.spans()) == tracing.INCIDENT_SPANS + 10
+    assert [s["attrs"]["i"] for s in spans] == list(
+        range(10, tracing.INCIDENT_SPANS + 10))
+
+
+def test_telemetry_loads_no_jax_and_spans_record_without_it():
+    """``repro.obs`` stays free of JAX: with JAX never loaded no profiler
+    can record, and an armed span records without its annotation."""
+    import subprocess
+    import sys
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = ("import sys\n"
+            "from repro import obs\n"
+            "from repro.obs.metrics import NOOP_SPAN\n"
+            "reg = obs.Registry(flight_spans=2)\n"
+            "assert not obs.tracing.profiling()\n"
+            "assert reg.span('wal.append') is NOOP_SPAN\n"
+            "with obs.armed():\n"
+            "    for _ in range(3):\n"
+            "        with reg.span('wal.append'):\n"
+            "            pass\n"
+            "assert [r['call'] for r in reg.spans()] == [1, 2]\n"
+            "assert reg.spans_full()\n"
+            "assert 'jax' not in sys.modules\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
+    proc = subprocess.run([sys.executable, "-c", code], cwd=repo, env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
