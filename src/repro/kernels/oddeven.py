@@ -1,7 +1,9 @@
 """Pallas TPU kernel: vectorised odd-even transposition over slab rows.
 
-The paper's lock-free bubble sort, as a VPU-only kernel.  Roll-based
-compare-exchange — no lane-strided slicing, no gathers — so every pass is a
+The paper's lock-free bubble sort, as a VPU-only kernel.  Each row tile
+first gathers its counts into order position (``c[r, i] = cnt[r,
+order[r, i]]``, a lane gather in VMEM), then runs the passes with a
+roll-based compare-exchange — no lane-strided slicing — so every pass is a
 handful of lane shifts + selects, ideal for the TPU vector unit:
 
   for each parity p in {even, odd}:
@@ -9,9 +11,12 @@ handful of lane shifts + selects, ideal for the TPU vector unit:
     gave_prev[i] = take_next[i-1]
     c'[i] = c[i+1] if take_next else (c[i-1] if gave_prev else c[i])
 
-VMEM tiling: a (ROWS_PER_BLOCK, C) tile of both the count-in-order array and
-the permutation; grid over row blocks.  C (slab capacity) is the lane dim;
-a C below 128 (the serving configs use 64) fills part of each vreg.
+VMEM tiling: a (ROWS_PER_BLOCK, C) tile of the counts in slot order and of
+the permutation in, a tile of the new permutation out; grid over row
+blocks.  The counts in order position live only in VMEM: per call the
+kernel reads two [N, C] arrays from HBM and writes one.  C (slab capacity)
+is the lane dim; a C below 128 (the serving configs use 64) fills part of
+each vreg.
 """
 
 from __future__ import annotations
@@ -42,38 +47,36 @@ def _compare_exchange(c, o, idx, parity):
     return new_c, new_o
 
 
-def _oddeven_kernel(c_ref, o_ref, c_out_ref, o_out_ref, *, passes: int):
-    c = c_ref[...]
+def _oddeven_kernel(cnt_ref, o_ref, o_out_ref, *, passes: int):
     o = o_ref[...]
+    # order holds a permutation of 0..C-1 in every row (padding rows: 0s)
+    c = jnp.take_along_axis(cnt_ref[...], o, axis=1,
+                            mode="promise_in_bounds")
     cap = c.shape[-1]
     idx = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
     for _ in range(passes):
         for parity in (0, 1):
             c, o = _compare_exchange(c, o, idx, parity)
-    c_out_ref[...] = c
     o_out_ref[...] = o
 
 
 @functools.partial(
     jax.jit, static_argnames=("passes", "rows_per_block", "interpret"))
-def oddeven_pallas(c_ord: jax.Array, order: jax.Array, *, passes: int = 1,
+def oddeven_pallas(cnt: jax.Array, order: jax.Array, *, passes: int = 1,
                    rows_per_block: int = DEFAULT_ROWS_PER_BLOCK,
                    interpret: bool):
-    """k odd-even passes. c_ord/order: [N, C], N divisible by rows_per_block
-    (ops.py pads). Returns (c_ord', order')."""
-    n, cap = c_ord.shape
+    """k odd-even passes. cnt: [N, C] counts in slot order; order: [N, C]
+    int32 slot permutation; N divisible by rows_per_block (ops.py pads).
+    Returns order'."""
+    n, cap = cnt.shape
     rb = min(rows_per_block, n)
     assert n % rb == 0, (n, rb)
-    grid = (n // rb,)
     spec = pl.BlockSpec((rb, cap), lambda i: (i, 0))
     return pl.pallas_call(
         functools.partial(_oddeven_kernel, passes=passes),
-        grid=grid,
+        grid=(n // rb,),
         in_specs=[spec, spec],
-        out_specs=[spec, spec],
-        out_shape=[
-            jax.ShapeDtypeStruct(c_ord.shape, c_ord.dtype),
-            jax.ShapeDtypeStruct(order.shape, order.dtype),
-        ],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(order.shape, order.dtype),
         interpret=interpret,
-    )(c_ord, order)
+    )(cnt, order)

@@ -1,11 +1,13 @@
 """jit'd public wrappers around the Pallas kernels.
 
-Handle padding to block multiples, the order-gather layout transform, and
-backend dispatch: ``impl='pallas'`` (compiled by Mosaic on a TPU, run by
-the Pallas interpreter on any other backend — how the CPU tests check
-kernel parity), ``impl='ref'`` (pure-jnp oracle), ``impl='auto'`` (pallas
-on TPU, ref otherwise — the ref *is* the XLA fast path on CPU).  On a TPU
-nothing falls back: a kernel that fails to compile raises to the caller.
+Handle padding to block multiples and backend dispatch: ``impl='pallas'``
+(compiled by Mosaic on a TPU, run by the Pallas interpreter on any other
+backend — how the CPU tests check kernel parity), ``impl='ref'`` (pure-jnp
+oracle), ``impl='auto'`` (pallas on TPU, ref otherwise — the ref *is* the
+XLA fast path on CPU).  On a TPU nothing falls back: a kernel that fails
+to compile raises to the caller.  Layout transforms a kernel needs (the
+odd-even sort's counts in order position) happen inside the kernel, in
+VMEM, not as an XLA pass over the state.
 """
 
 from __future__ import annotations
@@ -73,18 +75,19 @@ def oddeven_sort(cnt: jax.Array, order: jax.Array, *, passes: int = 1,
                  impl: str = "auto") -> jax.Array:
     """k odd-even passes over every slab row; returns the new order
     permutation (slabs themselves never move — DESIGN.md §2)."""
-    # kernel layout: gather counts into order position ONCE and carry them
-    # through the swaps, instead of re-gathering every half-pass (same
-    # semantics; see test_oddeven_ref_equals_slab_semantics)
-    c_ord = jnp.take_along_axis(cnt, order, axis=1)
     if _use_ref(impl):
+        # oracle layout: gather counts into order position ONCE and carry
+        # them through the swaps, instead of re-gathering every half-pass
+        # (same semantics; see test_oddeven_ref_equals_slab_semantics)
+        c_ord = jnp.take_along_axis(cnt, order, axis=1)
         _, new_order = _ref.oddeven_ref(c_ord, order, passes)
         return new_order
+    # the kernel makes that gather itself, per row tile in VMEM
     rb = min(_oe.DEFAULT_ROWS_PER_BLOCK, cnt.shape[0])
-    c_ord, n = _pad_rows(c_ord, rb, 0)
+    cnt_p, n = _pad_rows(cnt, rb, 0)
     order_p, _ = _pad_rows(order, rb, 0)
-    _, new_order = _oe.oddeven_pallas(
-        c_ord, order_p, passes=passes, rows_per_block=rb,
+    new_order = _oe.oddeven_pallas(
+        cnt_p, order_p, passes=passes, rows_per_block=rb,
         interpret=not _on_tpu())
     return new_order[:n]
 

@@ -19,7 +19,8 @@ def oddeven_ref(c_ord: jax.Array, order: jax.Array, passes: int):
     """k odd-even passes over counts-in-order + the order permutation.
 
     c_ord[N, C] are the counts *already gathered into order position* (the
-    kernel-side layout); order[N, C] the slot permutation. Returns the pair
+    layout the kernel builds per row tile in VMEM); order[N, C] the slot
+    permutation. Returns the pair
     after ``passes`` full (even+odd) sweeps, descending target.
     """
     for _ in range(passes):

@@ -11,6 +11,7 @@ from repro.core import slab as sl
 from repro.kernels import cdf_gather as cgk
 from repro.kernels import cdf_query as cdfk
 from repro.kernels import oddeven as oek
+from repro.kernels import ops
 from repro.kernels import probe as prk
 from repro.kernels import ref
 from repro.kernels import slab_update as suk
@@ -33,50 +34,61 @@ def _rand_slabs(rng, n, c, density=0.7, dtype=np.int32):
 # ---------------------------------------------------------------------------
 
 
+def _rand_perms(rng, n, c):
+    return jnp.asarray(
+        np.stack([rng.permutation(c) for _ in range(n)]).astype(np.int32))
+
+
+def _check_oddeven(cnt, order, got_o, passes):
+    """The kernel's permutation equals the oracle's on the counts gathered
+    into order position, and the counts it puts in order are the oracle's."""
+    c_ord = jnp.take_along_axis(cnt, order, axis=1)
+    want_c, want_o = ref.oddeven_ref(c_ord, order, passes)
+    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(want_o))
+    got_c = jnp.take_along_axis(cnt, got_o, axis=1)
+    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
+    return np.asarray(got_c)
+
+
 @pytest.mark.parametrize("n,c", SHAPES_2D)
 @pytest.mark.parametrize("passes", [1, 2, 5])
 def test_oddeven_kernel_matches_ref(n, c, passes):
     rng = np.random.default_rng(n * 1000 + c + passes)
     cnt = jnp.asarray(rng.integers(0, 100, (n, c)).astype(np.int32))
-    order = jnp.asarray(
-        np.stack([rng.permutation(c) for _ in range(n)]).astype(np.int32))
-    c_ord = jnp.take_along_axis(cnt, order, axis=1)
+    order = _rand_perms(rng, n, c)
     # pad rows to the block multiple the kernel requires
     rb = min(oek.DEFAULT_ROWS_PER_BLOCK, n)
     pad = (-n) % rb
-    c_pad = jnp.pad(c_ord, ((0, pad), (0, 0)))
+    c_pad = jnp.pad(cnt, ((0, pad), (0, 0)))
     o_pad = jnp.pad(order, ((0, pad), (0, 0)))
-    got_c, got_o = oek.oddeven_pallas(
+    got_o = oek.oddeven_pallas(
         c_pad, o_pad, passes=passes, rows_per_block=rb, interpret=True)
-    want_c, want_o = ref.oddeven_ref(c_ord, order, passes)
-    np.testing.assert_array_equal(np.asarray(got_c)[:n], np.asarray(want_c))
-    np.testing.assert_array_equal(np.asarray(got_o)[:n], np.asarray(want_o))
+    _check_oddeven(cnt, order, got_o[:n], passes)
 
 
 @pytest.mark.parametrize("dtype", [jnp.int32, jnp.float32])
 def test_oddeven_kernel_dtypes(dtype):
     rng = np.random.default_rng(0)
     cnt = jnp.asarray(rng.integers(0, 50, (16, 64))).astype(dtype)
-    order = jnp.asarray(
-        np.stack([rng.permutation(64) for _ in range(16)]).astype(np.int32))
-    c_ord = jnp.take_along_axis(cnt, order, axis=1)
-    got_c, got_o = oek.oddeven_pallas(
-        c_ord, order, passes=3, rows_per_block=16, interpret=True)
-    want_c, want_o = ref.oddeven_ref(c_ord, order, 3)
-    np.testing.assert_array_equal(np.asarray(got_c), np.asarray(want_c))
-    np.testing.assert_array_equal(np.asarray(got_o), np.asarray(want_o))
+    order = _rand_perms(rng, 16, 64)
+    got_o = oek.oddeven_pallas(
+        cnt, order, passes=3, rows_per_block=16, interpret=True)
+    _check_oddeven(cnt, order, got_o, 3)
 
 
 def test_oddeven_ref_equals_slab_semantics():
-    """kernel-layout oracle == core slab.oddeven_passes semantics."""
+    """kernel-layout oracle == core slab.oddeven_passes semantics, and the
+    kernel (which gathers in VMEM) == both."""
     rng = np.random.default_rng(1)
     cnt = jnp.asarray(rng.integers(0, 100, (32, 64)).astype(np.int32))
-    order = jnp.asarray(
-        np.stack([rng.permutation(64) for _ in range(32)]).astype(np.int32))
+    order = _rand_perms(rng, 32, 64)
     want = sl.oddeven_passes(cnt, order, 2)
     c_ord = jnp.take_along_axis(cnt, order, axis=1)
     _, got = ref.oddeven_ref(c_ord, order, 2)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    got_k = oek.oddeven_pallas(cnt, order, passes=2, rows_per_block=32,
+                               interpret=True)
+    np.testing.assert_array_equal(np.asarray(got_k), np.asarray(want))
 
 
 def test_oddeven_full_sort_after_C_passes():
@@ -84,13 +96,28 @@ def test_oddeven_full_sort_after_C_passes():
     n, c = 16, 64
     cnt = jnp.asarray(rng.integers(0, 10_000, (n, c)).astype(np.int32))
     order = jnp.broadcast_to(jnp.arange(c, dtype=jnp.int32), (n, c))
-    c_ord = jnp.take_along_axis(cnt, order, axis=1)
-    got_c, got_o = oek.oddeven_pallas(
-        c_ord, order, passes=c // 2 + 1, rows_per_block=n, interpret=True)
-    got_c = np.asarray(got_c)
+    passes = c // 2 + 1
+    got_o = oek.oddeven_pallas(
+        cnt, order, passes=passes, rows_per_block=n, interpret=True)
+    got_c = _check_oddeven(cnt, order, got_o, passes)
     assert np.all(got_c[:, :-1] >= got_c[:, 1:]), "not fully sorted"
     # permutation property retained
     assert np.all(np.sort(np.asarray(got_o), axis=1) == np.arange(c))
+
+
+@pytest.mark.parametrize("c", [64, 128])
+@pytest.mark.parametrize("passes", [1, 33])
+def test_oddeven_sort_pallas_equals_ref_on_ties(c, passes):
+    """ops.oddeven_sort's kernel path returns the oracle's permutation on
+    rows full of tied counts (a strict compare never swaps a tie), over a
+    row count that is not a multiple of the block."""
+    rng = np.random.default_rng(c + passes)
+    n = 300
+    cnt = jnp.asarray(rng.integers(0, 4, (n, c)).astype(np.int32))
+    order = _rand_perms(rng, n, c)
+    want = ops.oddeven_sort(cnt, order, passes=passes, impl="ref")
+    got = ops.oddeven_sort(cnt, order, passes=passes, impl="pallas")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
 # ---------------------------------------------------------------------------
@@ -289,7 +316,6 @@ def test_dh_find_tombstone_chains_probe_through():
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
 def test_decay_sort_matches_core_decay(impl):
     from repro.core import slab as slab_mod
-    from repro.kernels import ops
 
     rng = np.random.default_rng(7)
     n, c = 16, 32
@@ -317,7 +343,6 @@ def test_probe_flat_table_matches_core_lookup(t_size):
     """ops.ht_find == hashtable.lookup_batch on a real src table with
     tombstones, for both dispatches (4096 slots: the two-tile window of a
     table larger than one tile, wrapping at its end)."""
-    from repro.kernels import ops
 
     rng = np.random.default_rng(t_size)
     tab = ht.make(t_size)

@@ -12,6 +12,7 @@ it keeps it until it exits.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -80,8 +81,8 @@ def _kernel_case(name, c, spec):
                 (i32((B,)), i32((B,)), i32((B,)), i32((N, c)), i32((N, c)),
                  i32((N,))))
     if name == "oddeven":
-        return (lambda cn, o: oek.oddeven_pallas(cn, o, passes=1,
-                                                 interpret=False),
+        return (lambda cnt, order: oek.oddeven_pallas(cnt, order, passes=1,
+                                                      interpret=False),
                 (i32((N, c)), i32((N, c))))
     if name == "dh_find":
         h = mc.MCConfig(capacity=c).resolved_dst_table_size()
@@ -135,8 +136,9 @@ def on_tpu(monkeypatch):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize("program", ["update", "query"])
-def test_sharded_program_compiles_for_one_v5e_chip(topo, on_tpu, program):
+def _one_chip_program(topo, program):
+    """The one-chip sharded update or query program, compiled whole at
+    2^20 x 64."""
     mesh = Mesh(np.array(topo.devices[:1]), ("shard",))
     scfg = sh.ShardedConfig(
         base=mc.MCConfig(num_rows=N, capacity=64, sort_passes=1),
@@ -148,6 +150,22 @@ def test_sharded_program_compiles_for_one_v5e_chip(topo, on_tpu, program):
         jax.eval_shape(lambda: mc.init(scfg.base)))
     batch = jax.ShapeDtypeStruct((B,), jnp.int32, sharding=shard)
     if program == "update":
-        _compile(sh.make_update_fn(scfg, mesh), state, batch, batch, batch)
-    else:
-        _compile(sh.make_query_fn(scfg, mesh, 0.9, 16), state, batch)
+        return _compile(sh.make_update_fn(scfg, mesh), state, batch, batch,
+                        batch)
+    return _compile(sh.make_query_fn(scfg, mesh, 0.9, 16), state, batch)
+
+
+@pytest.mark.parametrize("program", ["update", "query"])
+def test_sharded_program_compiles_for_one_v5e_chip(topo, on_tpu, program):
+    _one_chip_program(topo, program)
+
+
+def test_update_program_gathers_no_whole_state(topo, on_tpu):
+    """The odd-even sort gathers counts into order position inside its
+    kernel: no XLA gather of the update program yields N x 64 elements."""
+    hlo = _one_chip_program(topo, "update").as_text()
+    gathers = re.findall(r"= \w+\[([\d,]*)\][^ ]* gather\(", hlo)
+    assert gathers, "no gather found: the HLO text changed form"
+    sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+             for dims in gathers]
+    assert max(sizes) < N * 64, sorted(sizes)
